@@ -270,10 +270,18 @@ def _reexec_on_mesh(tag: str, path: str, n_dev: int, child_args: list):
     CPU mesh and return its JSON, or None if this process already has the
     right mesh (or IS the child).  Drops any pre-existing device-count flag
     (last one wins in XLA flag parsing) and marks the child so it never
-    re-execs itself."""
+    re-execs itself.
+
+    These emitters time XLA:CPU only.  On an accelerator this process
+    already holds the device, and a CPU-forced child would measure the
+    host instead, so it refuses: the chip path is ``chip_smoke.py``."""
     in_child = os.environ.get(f"_REPRO_BENCH_{tag}_CHILD") == "1"
-    if in_child or (len(jax.devices()) == n_dev
-                    and jax.default_backend() == "cpu"):
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"benchmarks.micro times the forced-device CPU mesh only; this "
+            f"process runs on {jax.default_backend()!r}.  Run chip_smoke.py "
+            f"at the repo root for the accelerator path.")
+    if in_child or len(jax.devices()) == n_dev:
         return None
     env = dict(os.environ)
     flags = [f for f in env.get("XLA_FLAGS", "").split()

@@ -11,7 +11,7 @@ explicit extreme guard), or be one of two blessed idioms —
 * ``associative_scan``'s interleave, which adds two *disjointly*
   zero-interior-padded arrays (one operand is always the 0 padding).
 
-The lint inlines nested ``pjit`` calls (``jnp.where`` & friends trace as
+The lint inlines nested ``jit`` calls (``jnp.where`` & friends trace as
 sub-jaxprs) into one flat equation list, runs a forward taint pass and
 reports:
 
@@ -49,7 +49,7 @@ _GUARDS = frozenset({
     "min", "max", "clamp", "select_n", "reduce_min", "reduce_max",
 })
 _ARITH = frozenset({"add", "sub", "mul"})
-_INLINE_PRIMS = frozenset({"pjit", "closed_call", "core_call", "remat",
+_INLINE_PRIMS = frozenset({"jit", "closed_call", "core_call", "remat",
                            "checkpoint", "custom_jvp_call",
                            "custom_vjp_call"})
 
@@ -65,7 +65,7 @@ class _FakeLit:
 
 class _FlatEqn(NamedTuple):
     prim: str
-    invars: Tuple[Any, ...]   # Var | Literal | _FakeLit, pjit-resolved
+    invars: Tuple[Any, ...]   # Var | Literal | _FakeLit, jit-resolved
     outvars: Tuple[Any, ...]
     params: Dict[str, Any]
     eqn: Any                  # original JaxprEqn (for messages)
@@ -88,7 +88,7 @@ def _const_tainted(val) -> bool:
 
 def _flatten_into(jaxpr, consts: Sequence, sub: Dict[int, Any],
                   out: List[_FlatEqn]) -> Dict[int, Any]:
-    """Inline every pjit-like call into one flat equation list, rewriting
+    """Inline every jit-like call into one flat equation list, rewriting
     operand references through the call boundary."""
     env = dict(sub)
     for cv, c in zip(jaxpr.constvars, consts):
@@ -263,7 +263,7 @@ class _Lint:
 
 def lint_jaxpr(fn, avals: Sequence, *, program: str,
                tainted_args: Sequence[int] = ()) -> List[Violation]:
-    """Trace ``fn(*avals)``, inline nested pjit calls, and lint the flat
+    """Trace ``fn(*avals)``, inline nested jit calls, and lint the flat
     jaxpr.  ``tainted_args`` are flat positional indices whose values are
     full-range int32 (keys, directory boundaries)."""
     import jax

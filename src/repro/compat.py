@@ -1,10 +1,7 @@
-"""Version compatibility shims for the jax API surface this repo targets.
+"""The jax API spellings this repo targets (jax 0.9).
 
-The codebase is written against the modern spellings (``jax.shard_map`` with
-``check_vma``, ``jax.make_mesh(..., axis_types=...)``).  Older jaxlibs (e.g.
-0.4.x) expose the same functionality as ``jax.experimental.shard_map`` with
-``check_rep`` and a ``make_mesh`` without ``axis_types``.  Everything in the
-repo goes through these two wrappers so a single file owns the skew.
+Every shard_map and mesh in the repo goes through these three wrappers,
+so replication checking and axis types are set in one place.
 """
 from __future__ import annotations
 
@@ -13,26 +10,18 @@ from jax import lax
 
 
 def axis_size(axis_name) -> int:
-    """Static named-axis size inside shard_map, on any jax version."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)  # special-cased to the static size
+    """Static named-axis size inside shard_map."""
+    return lax.axis_size(axis_name)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication checking off, on any jax version."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    """``jax.shard_map`` with replication checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(axis_shapes, axis_names):
-    """``jax.make_mesh`` requesting Auto axis types where supported."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(axis_shapes, axis_names,
-                             axis_types=(axis_type.Auto,) * len(axis_names))
-    return jax.make_mesh(axis_shapes, axis_names)
+    """``jax.make_mesh`` with Auto axis types for every axis."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names))

@@ -3,8 +3,8 @@
 The paper's Stages 1-3 for one device's request array, as a two-phase
 Blelloch scan tiled for VMEM:
 
-  phase A (parallel over tiles): per-tile total (A,B,C) transform —
-          a pure reduction, one (8,128) VPU tile at a time;
+  phase A (parallel over tiles): per-tile total (A,B,C) transform,
+          one (8,128) VPU tile at a time;
   phase B (parallel over tiles, given the exclusive tile-prefix carries):
           intra-tile Hillis-Steele scan in the min-plus semiring +
           position emission.
@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..backend import default_interpret
 
@@ -83,147 +84,158 @@ def _stack_tile_transforms(is_push, valid):
 
 
 # ---------------------------------------------------- shared scan bodies ----
-def _tree_reduce(compose, flat):
-    """Log-step tree reduction over a flattened tile.  The tropical compose
-    is non-commutative: pair ADJACENT elements (2i, 2i+1) at every level so
-    the reduction respects the left-to-right request order."""
-    n = TILE
-    a, b, c = flat
-    while n > 1:
-        left = (a[0:n:2], b[0:n:2], c[0:n:2])
-        right = (a[1:n:2], b[1:n:2], c[1:n:2])
-        a, b, c = compose(left, right)
-        n //= 2
-    return a, b, c
+# Both tropical composes are exactly associative on the values the
+# transforms produce (A, C >= 0 for min-plus; every b >= -INF for
+# max-plus), so any scan schedule yields the same prefixes bit for bit.
+# The schedule below keeps every op 2-D on the (8, 128) tile, as Mosaic
+# requires: lane shifts and sublane shifts are ``pltpu.roll``, masks are
+# 2-D iotas, and the identity fill is only ever composed on the right.
+def _iota(axis):
+    return lax.broadcasted_iota(jnp.int32, (TILE_ROWS, TILE_LANES), axis)
 
 
-def _hillis_steele_exclusive(compose, fills, tr):
-    """Intra-tile exclusive scan (log2(TILE) Hillis-Steele steps + shift)."""
-    a, b, c = tr
-    f_a, f_b, f_c = fills
+def _shift_compose(compose, tr, shift, axis):
+    """One Hillis-Steele step along ``axis``: element i becomes
+    tr[i - shift] ∘ tr[i] where i >= shift, and is kept where i < shift."""
+    prev = tuple(pltpu.roll(x, shift, axis) for x in tr)
+    keep = _iota(axis) < shift
+    return tuple(jnp.where(keep, x, y)
+                 for x, y in zip(tr, compose(prev, tr)))
+
+
+def _lane_scan(compose, tr):
+    """Inclusive scan of each row of the tile, left to right."""
     shift = 1
-    while shift < TILE:
-        ap = jnp.concatenate([jnp.full((shift,), f_a, jnp.int32), a[:-shift]])
-        bp = jnp.concatenate([jnp.full((shift,), f_b, jnp.int32), b[:-shift]])
-        cp = jnp.concatenate([jnp.full((shift,), f_c, jnp.int32), c[:-shift]])
-        na, nb, nc = compose((ap, bp, cp), (a, b, c))
-        idx = lax.broadcasted_iota(jnp.int32, (TILE,), 0)
-        keep = idx < shift
-        a = jnp.where(keep, a, na)
-        b = jnp.where(keep, b, nb)
-        c = jnp.where(keep, c, nc)
+    while shift < TILE_LANES:
+        tr = _shift_compose(compose, tr, shift, 1)
         shift *= 2
-    a_x = jnp.concatenate([jnp.full((1,), f_a, jnp.int32), a[:-1]])
-    b_x = jnp.concatenate([jnp.full((1,), f_b, jnp.int32), b[:-1]])
-    c_x = jnp.concatenate([jnp.full((1,), f_c, jnp.int32), c[:-1]])
-    return a_x, b_x, c_x
+    return tr
+
+
+def _row_prefix(compose, lane_incl):
+    """Inclusive scan over the rows' totals, broadcast along the lanes:
+    row r holds the composition of rows 0..r."""
+    rows = tuple(jnp.broadcast_to(x[:, TILE_LANES - 1:], x.shape)
+                 for x in lane_incl)
+    shift = 1
+    while shift < TILE_ROWS:
+        rows = _shift_compose(compose, rows, shift, 0)
+        shift *= 2
+    return rows
+
+
+def _tile_totals(compose, tr):
+    """The tile's total transform, written as rows 0..2 of an (8, 128)
+    tile (component k in row k, every lane equal)."""
+    rows = _row_prefix(compose, _lane_scan(compose, tr))
+    last = [jnp.broadcast_to(x[TILE_ROWS - 1:, :], x.shape) for x in rows]
+    r = _iota(0)
+    return jnp.where(r == 0, last[0], jnp.where(r == 1, last[1], last[2]))
+
+
+def _tile_exclusive(compose, fills, tr):
+    """Exclusive row-major scan of the tile; element (0, 0) gets ``fills``."""
+    lane_incl = _lane_scan(compose, tr)
+    rows = _row_prefix(compose, lane_incl)
+    before = tuple(pltpu.roll(x, 1, 0) for x in rows)  # rows 0..r-1
+    r, j = _iota(0), _iota(1)
+    incl = tuple(jnp.where(r == 0, x, y)
+                 for x, y in zip(lane_incl, compose(before, lane_incl)))
+    return tuple(jnp.where(j > 0, pltpu.roll(x, 1, 1),
+                           jnp.where(r > 0, b, f))
+                 for x, b, f in zip(incl, before, fills))
 
 
 def _totals_kernel(is_enq_ref, valid_ref, out_ref):
     """Phase A: reduce one [8,128] tile to its total (A,B,C)."""
-    A, B, C = _tile_transforms(is_enq_ref[...], valid_ref[...])
-    a, b, c = _tree_reduce(
-        _compose, (A.reshape(-1), B.reshape(-1), C.reshape(-1)))
-    out_ref[0, 0] = a[0]
-    out_ref[0, 1] = b[0]
-    out_ref[0, 2] = c[0]
+    out_ref[0] = _tile_totals(
+        _compose, _tile_transforms(is_enq_ref[0], valid_ref[0]))
 
 
 def _scan_kernel(is_enq_ref, valid_ref, carry_ref, state_ref,
                  pos_ref, match_ref):
     """Phase B: intra-tile exclusive scan after the tile's carry."""
-    A, B, C = _tile_transforms(is_enq_ref[...], valid_ref[...])
-    a_x, b_x, c_x = _hillis_steele_exclusive(
-        _compose, (0, INF, 0),
-        (A.reshape(-1), B.reshape(-1), C.reshape(-1)))
+    is_enq, valid = is_enq_ref[0], valid_ref[0]
+    a_x, b_x, c_x = _tile_exclusive(
+        _compose, (0, INF, 0), _tile_transforms(is_enq, valid))
     # prepend the inter-tile carry and the initial anchor state
-    ca = carry_ref[0, 0]
-    cb = carry_ref[0, 1]
-    cc = carry_ref[0, 2]
-    a_x, b_x, c_x = _compose((ca, cb, cc), (a_x, b_x, c_x))
-    first0 = state_ref[0, 0]
-    last0 = state_ref[0, 1]
+    t = 3 * pl.program_id(0)
+    a_x, b_x, c_x = _compose(
+        (carry_ref[t], carry_ref[t + 1], carry_ref[t + 2]), (a_x, b_x, c_x))
+    first0 = state_ref[0]
+    last0 = state_ref[1]
     f_i = jnp.minimum(first0 + a_x, last0 + b_x)
     l_i = last0 + c_x
-    is_enq = (is_enq_ref[...].reshape(-1) != 0)
-    vmask = (valid_ref[...].reshape(-1) != 0)
-    pos = jnp.where(is_enq, l_i + 1,
+    pos = jnp.where(is_enq != 0, l_i + 1,
                     jnp.where(f_i <= l_i, f_i, jnp.int32(-1)))
-    pos = jnp.where(vmask, pos, jnp.int32(-1))
-    pos_ref[...] = pos.reshape(1, TILE_ROWS, TILE_LANES)
-    match_ref[...] = jnp.where(vmask, (pos >= 0), False).reshape(
-        1, TILE_ROWS, TILE_LANES).astype(jnp.int32)
+    pos = jnp.where(valid != 0, pos, jnp.int32(-1))
+    pos_ref[0] = pos
+    match_ref[0] = jnp.where(pos >= 0, 1, 0)
 
 
 def _stack_totals_kernel(is_push_ref, valid_ref, out_ref):
     """Phase A (max-plus): reduce one tile to its total (a, b, dt)."""
-    a, b, d = _stack_tile_transforms(is_push_ref[...], valid_ref[...])
-    a, b, d = _tree_reduce(
-        _stack_compose, (a.reshape(-1), b.reshape(-1), d.reshape(-1)))
-    out_ref[0, 0] = a[0]
-    out_ref[0, 1] = b[0]
-    out_ref[0, 2] = d[0]
+    out_ref[0] = _tile_totals(
+        _stack_compose,
+        _stack_tile_transforms(is_push_ref[0], valid_ref[0]))
 
 
 def _stack_scan_kernel(is_push_ref, valid_ref, carry_ref, state_ref,
                        pos_ref, tick_ref):
     """Phase B (max-plus): positions + tickets after the tile's carry."""
-    a, b, d = _stack_tile_transforms(is_push_ref[...], valid_ref[...])
-    a_x, b_x, d_x = _hillis_steele_exclusive(
-        _stack_compose, (0, -INF, 0),
-        (a.reshape(-1), b.reshape(-1), d.reshape(-1)))
-    ca = carry_ref[0, 0]
-    cb = carry_ref[0, 1]
-    cd = carry_ref[0, 2]
-    a_x, b_x, d_x = _stack_compose((ca, cb, cd), (a_x, b_x, d_x))
-    last0 = state_ref[0, 0]
-    tick0 = state_ref[0, 1]
+    is_push, valid = is_push_ref[0], valid_ref[0]
+    a_x, b_x, d_x = _tile_exclusive(
+        _stack_compose, (0, -INF, 0), _stack_tile_transforms(is_push, valid))
+    t = 3 * pl.program_id(0)
+    a_x, b_x, d_x = _stack_compose(
+        (carry_ref[t], carry_ref[t + 1], carry_ref[t + 2]), (a_x, b_x, d_x))
+    last0 = state_ref[0]
+    tick0 = state_ref[1]
     l_i = jnp.maximum(last0 + a_x, b_x)
     t_i = tick0 + d_x
-    is_push = (is_push_ref[...].reshape(-1) != 0)
-    vmask = (valid_ref[...].reshape(-1) != 0)
-    pos = jnp.where(is_push, l_i + 1,
+    pos = jnp.where(is_push != 0, l_i + 1,
                     jnp.where(l_i >= 1, l_i, jnp.int32(-1)))
-    pos = jnp.where(vmask, pos, jnp.int32(-1))
-    tick = jnp.where(is_push, t_i + 1, t_i)
-    pos_ref[...] = pos.reshape(1, TILE_ROWS, TILE_LANES)
-    tick_ref[...] = tick.reshape(1, TILE_ROWS, TILE_LANES)
+    pos_ref[0] = jnp.where(valid != 0, pos, jnp.int32(-1))
+    tick_ref[0] = jnp.where(is_push != 0, t_i + 1, t_i)
+
+
+def _tier_mask(tier_ref, enq_ref):
+    """This grid row's tier: the masked enqueues as int32."""
+    return jnp.logical_and(enq_ref[0] != 0,
+                           tier_ref[0] == pl.program_id(0)).astype(jnp.int32)
 
 
 def _tiered_totals_kernel(tier_ref, enq_ref, out_ref):
     """Phase A over grid (tier, tile): totals of THIS tier's enqueue mask."""
-    p = pl.program_id(0)
-    mask = jnp.logical_and(enq_ref[...] != 0,
-                           tier_ref[...] == p).astype(jnp.int32)
-    A, B, C = _tile_transforms(mask, mask)
-    a, b, c = _tree_reduce(
-        _compose, (A.reshape(-1), B.reshape(-1), C.reshape(-1)))
-    out_ref[0, 0, 0] = a[0]
-    out_ref[0, 0, 1] = b[0]
-    out_ref[0, 0, 2] = c[0]
+    mask = _tier_mask(tier_ref, enq_ref)
+    out_ref[0, 0] = _tile_totals(_compose, _tile_transforms(mask, mask))
 
 
 def _tiered_scan_kernel(tier_ref, enq_ref, carry_ref, state_ref, pos_ref):
     """Phase B over grid (tier, tile): per-tier enqueue positions."""
     p = pl.program_id(0)
-    mask32 = jnp.logical_and(enq_ref[...] != 0,
-                             tier_ref[...] == p).astype(jnp.int32)
-    A, B, C = _tile_transforms(mask32, mask32)
-    a_x, b_x, c_x = _hillis_steele_exclusive(
-        _compose, (0, INF, 0),
-        (A.reshape(-1), B.reshape(-1), C.reshape(-1)))
-    ca = carry_ref[0, 0, 0]
-    cb = carry_ref[0, 0, 1]
-    cc = carry_ref[0, 0, 2]
-    a_x, b_x, c_x = _compose((ca, cb, cc), (a_x, b_x, c_x))
-    last0 = state_ref[0, 1]
-    l_i = last0 + c_x
-    mask = (mask32.reshape(-1) != 0)
-    pos_ref[...] = jnp.where(mask, l_i + 1, jnp.int32(-1)).reshape(
-        1, 1, TILE_ROWS, TILE_LANES)
+    mask = _tier_mask(tier_ref, enq_ref)
+    a_x, b_x, c_x = _tile_exclusive(
+        _compose, (0, INF, 0), _tile_transforms(mask, mask))
+    t = 3 * (p * pl.num_programs(1) + pl.program_id(1))
+    a_x, b_x, c_x = _compose(
+        (carry_ref[t], carry_ref[t + 1], carry_ref[t + 2]), (a_x, b_x, c_x))
+    l_i = state_ref[2 * p + 1] + c_x
+    pos_ref[0, 0] = jnp.where(mask != 0, l_i + 1, jnp.int32(-1))
 
 
 # -------------------------------------------------------- entry points ------
+# Request tiles and per-tile results are (1, 8, 128) VMEM blocks; the
+# per-tile carries and the (first, last) state are whole flat int32 arrays
+# in SMEM, read as scalars at the grid step's own offset.
+_TILE_SPEC = pl.BlockSpec((1, TILE_ROWS, TILE_LANES), lambda t: (t, 0, 0))
+_SMEM_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _tiles(T):
+    return jax.ShapeDtypeStruct((T, TILE_ROWS, TILE_LANES), jnp.int32)
+
+
 def _carry_scan(compose, totals, ident_row, axis=0):
     """Inter-tile exclusive scan of the tiny per-tile carries (jnp)."""
     def comp(x, y):
@@ -259,40 +271,25 @@ def queue_scan_kernel(is_enq: jax.Array, valid: jax.Array,
     totals = pl.pallas_call(
         _totals_kernel,
         grid=(T,),
-        in_specs=[
-            pl.BlockSpec((1, TILE_ROWS, TILE_LANES), lambda t: (t, 0, 0)),
-            pl.BlockSpec((1, TILE_ROWS, TILE_LANES), lambda t: (t, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 3), lambda t: (t, 0)),
-        out_shape=jax.ShapeDtypeStruct((T, 3), jnp.int32),
+        in_specs=[_TILE_SPEC, _TILE_SPEC],
+        out_specs=_TILE_SPEC,
+        out_shape=_tiles(T),
         interpret=interpret,
-    )(e2, v2)
+    )(e2, v2)[:, :3, 0]
 
     # ---- inter-tile exclusive scan of carries (tiny; jnp) ----
     excl, tot = _carry_scan(_compose, totals, [0, INF, 0])
-    state = jnp.stack([first.astype(jnp.int32),
-                       last.astype(jnp.int32)])[None]  # [1, 2]
+    state = jnp.stack([first.astype(jnp.int32), last.astype(jnp.int32)])
 
     # ---- phase B: positions ----
     pos, match = pl.pallas_call(
         _scan_kernel,
         grid=(T,),
-        in_specs=[
-            pl.BlockSpec((1, TILE_ROWS, TILE_LANES), lambda t: (t, 0, 0)),
-            pl.BlockSpec((1, TILE_ROWS, TILE_LANES), lambda t: (t, 0, 0)),
-            pl.BlockSpec((1, 3), lambda t: (t, 0)),
-            pl.BlockSpec((1, 2), lambda t: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, TILE_ROWS, TILE_LANES), lambda t: (t, 0, 0)),
-            pl.BlockSpec((1, TILE_ROWS, TILE_LANES), lambda t: (t, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((T, TILE_ROWS, TILE_LANES), jnp.int32),
-            jax.ShapeDtypeStruct((T, TILE_ROWS, TILE_LANES), jnp.int32),
-        ],
+        in_specs=[_TILE_SPEC, _TILE_SPEC, _SMEM_SPEC, _SMEM_SPEC],
+        out_specs=[_TILE_SPEC, _TILE_SPEC],
+        out_shape=[_tiles(T), _tiles(T)],
         interpret=interpret,
-    )(e2, v2, excl, state)
+    )(e2, v2, excl.reshape(-1), state)
 
     new_first = jnp.minimum(first + tot[0], last + tot[1])
     new_last = last + tot[2]
@@ -319,38 +316,23 @@ def stack_scan_kernel(is_push: jax.Array, valid: jax.Array,
     totals = pl.pallas_call(
         _stack_totals_kernel,
         grid=(T,),
-        in_specs=[
-            pl.BlockSpec((1, TILE_ROWS, TILE_LANES), lambda t: (t, 0, 0)),
-            pl.BlockSpec((1, TILE_ROWS, TILE_LANES), lambda t: (t, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 3), lambda t: (t, 0)),
-        out_shape=jax.ShapeDtypeStruct((T, 3), jnp.int32),
+        in_specs=[_TILE_SPEC, _TILE_SPEC],
+        out_specs=_TILE_SPEC,
+        out_shape=_tiles(T),
         interpret=interpret,
-    )(e2, v2)
+    )(e2, v2)[:, :3, 0]
 
     excl, tot = _carry_scan(_stack_compose, totals, [0, -INF, 0])
-    state = jnp.stack([last.astype(jnp.int32),
-                       ticket.astype(jnp.int32)])[None]  # [1, 2]
+    state = jnp.stack([last.astype(jnp.int32), ticket.astype(jnp.int32)])
 
     pos, tick = pl.pallas_call(
         _stack_scan_kernel,
         grid=(T,),
-        in_specs=[
-            pl.BlockSpec((1, TILE_ROWS, TILE_LANES), lambda t: (t, 0, 0)),
-            pl.BlockSpec((1, TILE_ROWS, TILE_LANES), lambda t: (t, 0, 0)),
-            pl.BlockSpec((1, 3), lambda t: (t, 0)),
-            pl.BlockSpec((1, 2), lambda t: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, TILE_ROWS, TILE_LANES), lambda t: (t, 0, 0)),
-            pl.BlockSpec((1, TILE_ROWS, TILE_LANES), lambda t: (t, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((T, TILE_ROWS, TILE_LANES), jnp.int32),
-            jax.ShapeDtypeStruct((T, TILE_ROWS, TILE_LANES), jnp.int32),
-        ],
+        in_specs=[_TILE_SPEC, _TILE_SPEC, _SMEM_SPEC, _SMEM_SPEC],
+        out_specs=[_TILE_SPEC, _TILE_SPEC],
+        out_shape=[_tiles(T), _tiles(T)],
         interpret=interpret,
-    )(e2, v2, excl, state)
+    )(e2, v2, excl.reshape(-1), state)
 
     new_last = jnp.maximum(last + tot[0], tot[1])
     new_ticket = ticket + tot[2]
@@ -379,17 +361,20 @@ def tiered_queue_scan_kernel(tier: jax.Array, enq: jax.Array,
     t2 = tier.astype(jnp.int32).reshape(T, TILE_ROWS, TILE_LANES)
     e2 = enq.astype(jnp.int32).reshape(T, TILE_ROWS, TILE_LANES)
 
+    tile_spec = pl.BlockSpec((1, TILE_ROWS, TILE_LANES),
+                             lambda p, t: (t, 0, 0))
+    tier_spec = pl.BlockSpec((1, 1, TILE_ROWS, TILE_LANES),
+                             lambda p, t: (p, t, 0, 0))
+    tier_tiles = jax.ShapeDtypeStruct((P_, T, TILE_ROWS, TILE_LANES),
+                                      jnp.int32)
     totals = pl.pallas_call(
         _tiered_totals_kernel,
         grid=(P_, T),
-        in_specs=[
-            pl.BlockSpec((1, TILE_ROWS, TILE_LANES), lambda p, t: (t, 0, 0)),
-            pl.BlockSpec((1, TILE_ROWS, TILE_LANES), lambda p, t: (t, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, 3), lambda p, t: (p, t, 0)),
-        out_shape=jax.ShapeDtypeStruct((P_, T, 3), jnp.int32),
+        in_specs=[tile_spec, tile_spec],
+        out_specs=tier_spec,
+        out_shape=tier_tiles,
         interpret=interpret,
-    )(t2, e2)
+    )(t2, e2)[:, :, :3, 0]
 
     excl, tot = _carry_scan(_compose, totals, [0, INF, 0], axis=1)
     state = jnp.stack([firsts.astype(jnp.int32),
@@ -398,18 +383,11 @@ def tiered_queue_scan_kernel(tier: jax.Array, enq: jax.Array,
     pos_all = pl.pallas_call(
         _tiered_scan_kernel,
         grid=(P_, T),
-        in_specs=[
-            pl.BlockSpec((1, TILE_ROWS, TILE_LANES), lambda p, t: (t, 0, 0)),
-            pl.BlockSpec((1, TILE_ROWS, TILE_LANES), lambda p, t: (t, 0, 0)),
-            pl.BlockSpec((1, 1, 3), lambda p, t: (p, t, 0)),
-            pl.BlockSpec((1, 2), lambda p, t: (p, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, TILE_ROWS, TILE_LANES),
-                               lambda p, t: (p, t, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((P_, T, TILE_ROWS, TILE_LANES),
-                                       jnp.int32),
+        in_specs=[tile_spec, tile_spec, _SMEM_SPEC, _SMEM_SPEC],
+        out_specs=tier_spec,
+        out_shape=tier_tiles,
         interpret=interpret,
-    )(t2, e2, excl, state)
+    )(t2, e2, excl.reshape(-1), state.reshape(-1))
 
     new_lasts = lasts + tot[:, 2]
     return pos_all.reshape(P_, n), new_lasts.astype(jnp.int32)

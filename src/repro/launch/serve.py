@@ -11,6 +11,7 @@ import jax
 import numpy as np
 
 from ..configs import get_config
+from ..launch.compile_cache import enable_compile_cache
 from ..launch.mesh import make_host_mesh
 from ..models import build_model
 from ..serve import Request, ServeEngine
@@ -23,6 +24,7 @@ def main():
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=6)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch).reduced()
     model = build_model(cfg)

@@ -2,9 +2,8 @@
 
 Every ``init_*`` returns ``(params, logical_axes)`` with identical tree
 structure; logical axis names are mapped to mesh axes by
-``repro.sharding.specs``.  Compute defaults to the pure-jnp path (used by the
-multi-pod dry-run: XLA fuses it); the Pallas kernels are switched in with
-``use_pallas=True`` on real TPU.
+``repro.sharding.specs``.  Compute is the pure-jnp path on every backend;
+XLA fuses it.
 """
 from __future__ import annotations
 

@@ -1,4 +1,9 @@
-"""Localhost multi-process launcher for DistributedRuntime.
+"""Localhost multi-process launcher for DistributedRuntime: a CPU test
+harness, not a chip path.
+
+Every child it starts is forced onto the CPU (``JAX_PLATFORMS=cpu`` and a
+forced device count), so it never touches an accelerator; ``chip_smoke.py``
+at the repo root is the path that runs on the chip.
 
 Spawns ``n_procs`` python subprocesses, each forced to
 ``devs_per_proc`` CPU devices, wired to one coordinator port via the

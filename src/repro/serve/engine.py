@@ -96,6 +96,23 @@ from ..obs.trace import span
 from .admission import AdmissionRejected, PressureSignal, resolve_policy
 
 
+def slot_decode(model):
+    """The engine's decode step: ``model.decode_fn`` vmapped over slots.
+
+    Called as ``(params, cache, toks[B, 1], idxs[B])`` with cache leaves
+    batched on axis 1 (``[layers, B, ...]``); every slot decodes at ITS
+    OWN position, and the unit batch dim the model expects is re-added
+    inside the map.  Returns ``(logits[B, ...], cache)``.
+    """
+    def _one(p, c, t, i):
+        c = jax.tree.map(lambda x: x[:, None], c)
+        lg, nc = model.decode_fn(p, c, t[None], i)
+        nc = jax.tree.map(lambda x: x[:, 0], nc)
+        return lg[0], nc
+
+    return jax.jit(jax.vmap(_one, in_axes=(None, 1, 0, 0), out_axes=(0, 1)))
+
+
 @dataclasses.dataclass
 class Request:
     """One serving request and its lifecycle bookkeeping.
@@ -228,17 +245,14 @@ class ServeEngine:
         self.step_no = 0
         self._staged: List[int] = []   # rids submitted but not yet flushed
         self._host_qsize = 0           # host mirror of the device queue size
-        # vmap over slots: each slot decodes at ITS OWN position (cache leaves
-        # have batch on axis 1: [layers, B, ...]); re-add the unit batch dim
-        # the model expects inside the map
-        def _one(p, c, t, i):
-            c = jax.tree.map(lambda x: x[:, None], c)
-            lg, nc = model.decode_fn(p, c, t[None], i)
-            nc = jax.tree.map(lambda x: x[:, 0], nc)
-            return lg[0], nc
-
-        self._decode = jax.jit(jax.vmap(
-            _one, in_axes=(None, 1, 0, 0), out_axes=(0, 1)))
+        self._decode = slot_decode(model)
+        # a refilled slot starts from the zero cache init_cache gives: a
+        # recurrent (SSM) state left by the slot's previous request would
+        # otherwise leak into the new one
+        self._clear_slots = jax.jit(lambda c, fresh: jax.tree.map(
+            lambda x: jnp.where(
+                fresh.reshape((1, -1) + (1,) * (x.ndim - 2)),
+                jnp.zeros((), x.dtype), x), c))
         self.stats = {"served": 0, "queue_waits": [],
                       "queue_waits_by_prio": {p: [] for
                                               p in range(priorities)},
@@ -497,6 +511,10 @@ class ServeEngine:
         with span("serve:refill", cat="serve", step=self.step_no,
                   enq=len(enq_rids), free=len(free)):
             got = self._queue_wave(enq_rids, len(free))
+        if got:
+            fresh = np.zeros(self.max_slots, bool)
+            fresh[free[:len(got)]] = True
+            self.cache = self._clear_slots(self.cache, jnp.asarray(fresh))
         for slot, rid in zip(free, got):
             r = self.requests[rid]
             r.start_step = self.step_no

@@ -1,13 +1,9 @@
-"""Explicit branch coverage for src/repro/compat.py (the jax skew shims).
+"""src/repro/compat.py: the three wrappers pass the settings the repo relies
+on (replication checking off, Auto axis types) to the jax 0.9 API.
 
-Each wrapper picks its branch by ``hasattr`` AT CALL TIME, so both branches
-are testable on any installed jax: the new-API branch by installing a
-recording stub of the modern symbol, the old-API branch by deleting it.
-These are the code paths the CI ``jax-skew`` matrix runs for real on the
-oldest-supported and latest jax pins; the unit tests here pin the branch
-*selection* logic itself, on whatever version the runner has."""
+Each wrapper looks its jax symbol up at call time, so a recording stub
+installed with monkeypatch sees exactly what compat passes."""
 import numpy as np
-import pytest
 
 import jax
 import jax.numpy as jnp
@@ -21,19 +17,17 @@ def _mesh1():
     return jax.sharding.Mesh(np.array(jax.devices()[:1]), ("data",))
 
 
-# ---------------------------------------------------------- shard_map ------
 def test_shard_map_new_api_branch(monkeypatch):
-    """With ``jax.shard_map`` present, compat must use it and pass
-    ``check_vma=False`` (the modern spelling of check_rep)."""
+    """compat must call ``jax.shard_map`` with ``check_vma=False``."""
     calls = {}
+    real = jax.shard_map
 
     def fake_shard_map(f, *, mesh, in_specs, out_specs, check_vma):
         calls["check_vma"] = check_vma
-        from jax.experimental.shard_map import shard_map as real
         return real(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    check_rep=False)
+                    check_vma=check_vma)
 
-    monkeypatch.setattr(jax, "shard_map", fake_shard_map, raising=False)
+    monkeypatch.setattr(jax, "shard_map", fake_shard_map)
     fn = compat.shard_map(lambda x: x * 2, mesh=_mesh1(), in_specs=(P(),),
                           out_specs=P())
     out = fn(jnp.arange(4))
@@ -41,45 +35,15 @@ def test_shard_map_new_api_branch(monkeypatch):
     assert (np.asarray(out) == 2 * np.arange(4)).all()
 
 
-def test_shard_map_old_api_branch(monkeypatch):
-    """Without ``jax.shard_map``, compat must fall back to
-    ``jax.experimental.shard_map`` (the 0.4.x spelling)."""
-    monkeypatch.delattr(jax, "shard_map", raising=False)
-    assert not hasattr(jax, "shard_map")
-    fn = compat.shard_map(lambda x: x + 1, mesh=_mesh1(), in_specs=(P(),),
-                          out_specs=P())
-    out = fn(jnp.arange(4))
-    assert (np.asarray(out) == np.arange(4) + 1).all()
-
-
-# ---------------------------------------------------------- axis_size ------
 def test_axis_size_new_api_branch(monkeypatch):
-    """With ``lax.axis_size`` present, compat must return its answer."""
+    """compat must return ``lax.axis_size``'s answer."""
     sentinel = jnp.int32(12345)
-    monkeypatch.setattr(lax, "axis_size", lambda name: sentinel,
-                        raising=False)
+    monkeypatch.setattr(lax, "axis_size", lambda name: sentinel)
     assert int(compat.axis_size("data")) == 12345
 
 
-def test_axis_size_old_api_branch(monkeypatch):
-    """Without ``lax.axis_size``, compat must derive the size via psum
-    (special-cased to the static axis size inside shard_map)."""
-    monkeypatch.delattr(lax, "axis_size", raising=False)
-    assert not hasattr(lax, "axis_size")
-
-    def body(x):
-        return x + compat.axis_size("data")
-
-    fn = compat.shard_map(body, mesh=_mesh1(), in_specs=(P(),),
-                          out_specs=P())
-    out = fn(jnp.zeros((2,), jnp.int32))
-    assert (np.asarray(out) == 1).all()  # one device on the axis
-
-
-# ---------------------------------------------------------- make_mesh ------
 def test_make_mesh_new_api_branch(monkeypatch):
-    """With ``jax.sharding.AxisType`` present, compat must request Auto
-    axis types for every axis."""
+    """compat must request Auto axis types for every axis."""
     class FakeAxisType:
         Auto = "auto-sentinel"
 
@@ -89,33 +53,13 @@ def test_make_mesh_new_api_branch(monkeypatch):
         calls["axis_types"] = axis_types
         return "mesh-sentinel"
 
-    monkeypatch.setattr(jax.sharding, "AxisType", FakeAxisType,
-                        raising=False)
+    monkeypatch.setattr(jax.sharding, "AxisType", FakeAxisType)
     monkeypatch.setattr(jax, "make_mesh", fake_make_mesh)
     assert compat.make_mesh((1, 1), ("a", "b")) == "mesh-sentinel"
     assert calls == {"axis_types": ("auto-sentinel", "auto-sentinel")}
-
-
-def test_make_mesh_old_api_branch(monkeypatch):
-    """Without ``AxisType``, compat must call make_mesh WITHOUT the
-    axis_types kwarg (0.4.x raises TypeError on it)."""
-    monkeypatch.delattr(jax.sharding, "AxisType", raising=False)
-
-    def strict_make_mesh(axis_shapes, axis_names):  # no axis_types accepted
-        return ("mesh-sentinel", axis_shapes, axis_names)
-
-    monkeypatch.setattr(jax, "make_mesh", strict_make_mesh)
-    out = compat.make_mesh((1,), ("data",))
-    assert out == ("mesh-sentinel", (1,), ("data",))
 
 
 def test_make_mesh_builds_a_real_mesh():
     """End to end on the installed jax: a usable 1-device mesh."""
     mesh = compat.make_mesh((1,), ("data",))
     assert mesh.shape["data"] == 1
-
-
-def test_shard_map_experimental_fallback_exists():
-    """The repo's oldest-supported jax must ship the fallback module; if
-    this import ever breaks, compat.shard_map's old-API branch is dead."""
-    pytest.importorskip("jax.experimental.shard_map")
