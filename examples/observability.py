@@ -2,7 +2,8 @@
 
 1. device metrics — every wave leaves one summary row in a donated
    device-side ring (ZERO extra collectives); drained at burst ends,
-2. host tracing — span API + Chrome-trace/perfetto export,
+2. host tracing — span API (each burst's launch and overflow wait
+   included) + per-name summary + Chrome-trace/perfetto export,
 3. flight recorder — an overflow arrives with the occupancy trajectory
    that led to it,
 4. exposition — ServeEngine.metrics() -> JSON / Prometheus text.
@@ -13,7 +14,7 @@ import numpy as np
 
 import jax
 
-from repro.obs import span, timers, to_prometheus, tracer
+from repro.obs import span, to_prometheus, tracer
 
 
 def section_device_metrics():
@@ -28,11 +29,11 @@ def section_device_metrics():
     is_enq = rng.random((K, n)) < 0.7
     valid = rng.random((K, n)) < 0.8
     payload = rng.integers(0, 99, (K, n, 2)).astype(np.int32)
-    with timers("burst"):
-        q.run_waves(is_enq, valid, payload)
+    q.run_waves(is_enq, valid, payload)
     rows = q.trajectory()   # drained into the flight recorder at burst end
+    burst = tracer.summary()["queue:burst"]
     print(f"[device]   {len(rows)} wave rows drained after one "
-          f"{timers('burst').elapsed('last') * 1e3:.1f} ms burst:")
+          f"{burst['last_s'] * 1e3:.1f} ms burst:")
     for r in rows[:3]:
         print(f"           wave {r['seq']}: +{r['puts']} puts "
               f"-{r['gets']} gets  occ={r['occ']}  "
@@ -47,9 +48,11 @@ def section_tracing(tmp="wavescope_trace.json"):
             pass
     path = tracer.export_chrome_trace(tmp)
     names = [e["name"] for e in tracer.events()]
-    print(f"[trace]    {len(names)} spans recorded "
-          f"(incl. {[n for n in names if n.endswith('burst')][:1]}); "
-          f"open {path} in ui.perfetto.dev")
+    print(f"[trace]    {len(names)} spans recorded; open {path} in "
+          "ui.perfetto.dev.  Per name (count, total):")
+    for name, s in tracer.summary().items():
+        print(f"           {name:24s} {s['count']:3d} "
+              f"{s['total_s'] * 1e3:9.3f} ms")
 
 
 def section_flight_recorder():

@@ -167,3 +167,54 @@ def count_all_to_all(jitted, args: Sequence) -> int:
     tests: number of all-to-all ops (async pairs counted once) in the
     compiled module of ``jitted(*args)``."""
     return count_op(compiled_text(jitted, tuple(args)), "all-to-all")
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+)\s*=")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLED = re.compile(r"\b(?:calls|body|condition|to_apply)=%?([\w.\-]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+
+
+def scope_table(text: str, scopes: Sequence[str]) -> Dict[str, str]:
+    """Instruction name -> scope, for the instructions of HLO module text
+    whose ``op_name`` metadata lies under one of ``scopes`` (the innermost
+    named one wins).  An instruction without such metadata takes the scope
+    of the instruction that calls its computation (a fusion's, or a loop's
+    whose body it is), where every caller agrees; instructions the compiler
+    made without metadata are thereby put where they came from."""
+    wanted = set(scopes)
+    own: Dict[str, str] = {}
+    home: Dict[str, str] = {}          # instruction -> its computation
+    callers: Dict[str, List[str]] = {}  # computation -> calling instructions
+    comp = ""
+    for line in text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m and not line.startswith((" ", "\t")):
+            comp = m.group(1)
+            continue
+        m = _INSTRUCTION.match(line)
+        if not m:
+            continue
+        name = m.group(1)
+        home[name] = comp
+        meta = _OP_NAME.search(line)
+        if meta:
+            hit = [p for p in meta.group(1).split("/") if p in wanted]
+            if hit:
+                own[name] = hit[-1]
+        called = _CALLED.findall(line)
+        for b in _BRANCHES.findall(line):
+            called += [c.strip().lstrip("%") for c in b.split(",")]
+        for c in called:
+            callers.setdefault(c, []).append(name)
+
+    memo: Dict[str, str] = {}
+
+    def scope_of(name: str) -> str:      # HLO calls form no cycle
+        if name not in memo:
+            up = {scope_of(c) for c in callers.get(home[name], [])}
+            memo[name] = own.get(name) or (up.pop() if len(up) == 1 else "")
+        return memo[name]
+
+    return {name: scope_of(name) for name in home if scope_of(name)}

@@ -54,8 +54,9 @@ from ..core.scan_queue import (QueueState, StackState, sharded_queue_scan,
                                stack_scan)
 from ..kernels.backend import use_fused_dispatch
 from .wave_engine import (TAG_GET, TAG_INACTIVE, TAG_PUT, Discipline,
-                          Dispatch, WaveEngine, build_send,
-                          post_enqueue_peak_overflow, ring_commit)
+                          Dispatch, WaveEngine, build_send, named,
+                          post_enqueue_peak_overflow, program_name,
+                          ring_commit)
 
 
 class DeviceQueueState(NamedTuple):
@@ -78,6 +79,7 @@ class DeviceQueueState(NamedTuple):
 class FifoDiscipline(Discipline):
     """SKUEUE FIFO order: min-plus hypercube scan + dense-ring commit."""
 
+    name = "fifo"
     n_ops = 3           # (is_enq, valid, payload)
     n_disp_outs = 2     # (pos, matched)
 
@@ -277,8 +279,12 @@ class DeviceQueue:
 
     def _build_legacy_step(self):
         state_specs = self._state_specs
+
+        def step(state, is_enq, valid, payload):
+            return self._legacy_wave(state, is_enq, valid, payload)
+
         wrapped = shard_map(
-            self._legacy_wave, mesh=self.mesh,
+            named(step, program_name("fifo", "legacy_step")), mesh=self.mesh,
             in_specs=(state_specs, P(self.axis), P(self.axis), P(self.axis)),
             out_specs=(state_specs, P(self.axis), P(self.axis), P(self.axis),
                        P(self.axis), P()))
@@ -298,7 +304,8 @@ class DeviceQueue:
             return st, pos, matched, dv, dok, ovf
 
         wrapped = shard_map(
-            multi, mesh=self.mesh,
+            named(multi, program_name("fifo", "legacy_waves")),
+            mesh=self.mesh,
             in_specs=(state_specs, P(None, self.axis), P(None, self.axis),
                       P(None, self.axis)),
             out_specs=(state_specs, P(None, self.axis), P(None, self.axis),
@@ -316,6 +323,7 @@ class LifoDiscipline(Discipline):
     concurrent pops conflict-free (each pop takes the unique max ticket
     <= its bound)."""
 
+    name = "lifo"
     n_ops = 3           # (is_push, valid, payload)
     n_disp_outs = 2     # (pos, matched)
     extra_fill = (-1,)  # the ticket/bound request column

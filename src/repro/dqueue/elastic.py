@@ -75,8 +75,8 @@ from ..obs.recorder import FlightRecorder
 from ..obs.trace import span
 from .device_queue import DeviceQueue, DeviceQueueState, DeviceStack
 from .errors import QueueOverflowError
-from .wave_engine import (fanout_bound, migrate_packed, recover_positions,
-                          rewrite_ring_store)
+from .wave_engine import (fanout_bound, migrate_packed, named,
+                          program_name, recover_positions, rewrite_ring_store)
 
 HASH_BALANCE_MAX_SIZE = 1 << 16  # skip the fidelity report for huge queues
 
@@ -89,7 +89,9 @@ class _ElasticBase:
     """Shared machinery: device bookkeeping, mesh/inner/migration caches,
     the resize driver, migration stats, and checkpoint save/restore."""
 
-    _kind: str  # "queue" | "stack"
+    _kind: str  # span prefix: "queue" | "stack" | "pqueue" | "squeue"
+    _discipline: str  # program-name part: "fifo" | "lifo" | "prio" | "seap"
+    _ovf_out: int  # index of the overflow flag among a wave's outputs
 
     def __init__(self, n_shards: int, *, axis_name: str = "data",
                  cap: int = 1024, payload_width: int = 4,
@@ -123,6 +125,9 @@ class _ElasticBase:
         self._mesh_cache: Dict[tuple, jax.sharding.Mesh] = {}
         self._inner_cache: Dict[tuple, object] = {}
         self._mig_cache: Dict[tuple, list] = {}
+        self._burst_seq = 0
+        from ..analysis.recompile import CompilationTracker
+        CompilationTracker.install()
         self.inner = self._get_inner(self._mesh_for(self._active))
         self.state = self.inner.init_state()
         self.migrations: List[dict] = []
@@ -149,7 +154,8 @@ class _ElasticBase:
         key = (_mesh_key(mesh.devices.flat), P_old, P_new)
         if key not in self._mig_cache:
             fn = self._build_migration(mesh, P_old, P_new)
-            self._mig_cache[key] = [fn, None]  # [jitted, collective count]
+            # [jitted, its compiled executable, collective count]
+            self._mig_cache[key] = [fn, None, None]
         return self._mig_cache[key]
 
     # ---------------------------------------------------------- overflow ---
@@ -282,16 +288,39 @@ class _ElasticBase:
         from .wave_engine import pick_bucket_width
         return pick_bucket_width(self.L, self.n_shards, n_ops)
 
-    def _burst_span(self, K: int):
-        """Span wrapping one multi-wave burst dispatch.  Also the
-        runtime's burst-boundary latency hook (SimRuntime charges the
-        modeled K+1 pipelined / 2K sequential all_to_all launches;
-        no-op everywhere else)."""
-        self.runtime.on_burst(self._kind, int(K), self.n_shards,
-                              width=self.L, payload_width=self.W,
-                              pipelined=self.pipelined)
-        return span(f"{self._kind}:burst", cat="wave", K=int(K),
-                    n_shards=self.n_shards)
+    def _burst(self, entry, ops, lead: int) -> tuple:
+        """One wave (``lead`` 0) or K-wave burst (``lead`` 1, ops shaped
+        ``[K, n_shards * L, ...]``) of ``entry`` on the current mesh; the
+        state is threaded internally.  Returns the wave outputs; raises
+        :class:`~.errors.QueueOverflowError` when a wave overflowed.
+
+        Spans, each with the burst's sequence number ``burst``:
+        ``<kind>:burst`` around the whole call; inside it
+        ``<kind>:launch`` (placing the op arrays, the runtime's burst hook
+        — SimRuntime charges its modeled all_to_all launches there — and
+        the enqueue of the program) and the overflow check's spans."""
+        seq = self._burst_seq
+        self._burst_seq += 1
+        K = int(np.shape(ops[0])[0]) if lead else 1
+        with span(f"{self._kind}:burst", cat="wave", K=K,
+                  n_shards=self.n_shards, burst=seq):
+            with span(f"{self._kind}:launch", cat="wave", burst=seq):
+                self.runtime.on_burst(self._kind, K, self.n_shards,
+                                      width=self.L, payload_width=self.W,
+                                      pipelined=self.pipelined)
+                placed = [self._place(x, lead) for x in ops]
+                self.state, *out = entry(self.state, *placed)
+            self._check_overflow(out[self._ovf_out], seq)
+        return tuple(out)
+
+    def wave_phases(self) -> list:
+        """For each wave program this structure has dispatched, on every
+        membership it has had: instruction name -> wave phase, from the
+        program's compiled text (see ``WaveEngine.phase_tables``).  Read
+        after the fact; nothing compiles."""
+        return [t for inner in self._inner_cache.values()
+                if getattr(inner, "engine", None) is not None
+                for t in inner.engine.phase_tables()]
 
     def _place(self, x, lead: int = 0):
         """Stage one op array onto the active mesh via the runtime
@@ -300,16 +329,22 @@ class _ElasticBase:
         DistributedRuntime)."""
         return self.runtime.place(x, self.mesh, lead)
 
-    def _check_overflow(self, ovf) -> None:
+    def _check_overflow(self, ovf, burst: int) -> None:
         """Drain telemetry, then host-raise the wave's replicated
         overflow flag as a structured
         :class:`~.errors.QueueOverflowError` (was a bare assert in every
         caller before PR 5) carrying the flight-recorder trajectory.
         ``ovf`` is a scalar bool (``step``) or a [K] vector
         (``run_waves``); this runs once per step/burst, so the recorder
-        sees every wave even when nothing overflowed."""
-        self._drain_telemetry()
-        o = self.runtime.to_host(ovf)   # replicated scalar/[K] — cheap
+        sees every wave even when nothing overflowed.  Reading the flag
+        blocks until the burst has finished on the device: that wait is
+        the ``<kind>:overflow_wait`` span."""
+        if self.metrics:
+            with span(f"{self._kind}:telemetry_drain", cat="wave",
+                      burst=burst):
+                self._drain_telemetry()
+        with span(f"{self._kind}:overflow_wait", cat="wave", burst=burst):
+            o = self.runtime.to_host(ovf)   # replicated scalar/[K] — cheap
         if not bool(o.any()):
             return
         wave = int(np.flatnonzero(o)[0]) if o.ndim >= 1 else None
@@ -452,24 +487,33 @@ class _ElasticBase:
             shard = NamedSharding(mig_mesh, P(self.axis))
             rep = NamedSharding(mig_mesh, P())
             fx, fy = self._pad_fill
-            Xh, Yh = rt.to_host(X), rt.to_host(Y)
-            pad = P_new - P_old
-            Xh = np.concatenate(
-                [Xh, np.full((pad,) + Xh.shape[1:], fx, Xh.dtype)])
-            Yh = np.concatenate(
-                [Yh, np.full((pad,) + Yh.shape[1:], fy, Yh.dtype)])
-            a = rt.put(rt.to_host(a), rep)
-            b = rt.put(rt.to_host(b), rep)
-            X, Y = rt.put(Xh, shard), rt.put(Yh, shard)
+            with span("migration:stage", cat="membership"):
+                Xh, Yh = rt.to_host(X), rt.to_host(Y)
+                pad = P_new - P_old
+                Xh = np.concatenate(
+                    [Xh, np.full((pad,) + Xh.shape[1:], fx, Xh.dtype)])
+                Yh = np.concatenate(
+                    [Yh, np.full((pad,) + Yh.shape[1:], fy, Yh.dtype)])
+                a = rt.put(rt.to_host(a), rep)
+                b = rt.put(rt.to_host(b), rep)
+                X, Y = rt.put(Xh, shard), rt.put(Yh, shard)
         else:
             # shrink: route on the OLD mesh (owners are surviving shards)
             mig_mesh = self.mesh
 
         entry = self._migration_for(mig_mesh, P_old, P_new)
-        if self._hlo_stats and entry[1] is None:
-            entry[1] = self._count_all_to_all(entry[0], (a, b, X, Y))
+        t_compile = time.perf_counter()
+        if entry[1] is None:
+            # compiled ahead of its first use, so that the wave below
+            # times the migration alone
+            with span("migration:compile", cat="membership"):
+                entry[1] = entry[0].lower(a, b, X, Y).compile()
+        t_compile = time.perf_counter() - t_compile
+        if self._hlo_stats and entry[2] is None:
+            from ..analysis.hlo import count_op
+            entry[2] = count_op(entry[1].as_text(), "all-to-all")
         t_wave = time.perf_counter()
-        a, b, X, Y, moved, lost = entry[0](a, b, X, Y)
+        a, b, X, Y, moved, lost = entry[1](a, b, X, Y)
         jax.block_until_ready(Y)
         t_wave = time.perf_counter() - t_wave
         if bool(rt.to_host(lost)):
@@ -478,13 +522,14 @@ class _ElasticBase:
 
         if P_new < P_old:
             # drop the emptied rows, land on the smaller mesh
-            new_mesh = self._mesh_for(new_active)
-            shard = NamedSharding(new_mesh, P(self.axis))
-            rep = NamedSharding(new_mesh, P())
-            a = rt.put(rt.to_host(a), rep)
-            b = rt.put(rt.to_host(b), rep)
-            X = rt.put(rt.to_host(X)[:P_new], shard)
-            Y = rt.put(rt.to_host(Y)[:P_new], shard)
+            with span("migration:land", cat="membership"):
+                new_mesh = self._mesh_for(new_active)
+                shard = NamedSharding(new_mesh, P(self.axis))
+                rep = NamedSharding(new_mesh, P())
+                a = rt.put(rt.to_host(a), rep)
+                b = rt.put(rt.to_host(b), rep)
+                X = rt.put(rt.to_host(X)[:P_new], shard)
+                Y = rt.put(rt.to_host(Y)[:P_new], shard)
 
         self.state = self._pack(a, b, X, Y)
         self._active = list(new_active)
@@ -494,9 +539,10 @@ class _ElasticBase:
             "kind": kind, "P_from": P_old, "P_to": P_new,
             "moved": n_moved,
             "bytes_moved": n_moved * self._entry_bytes,
+            "compile_s": t_compile,
             "wave_s": t_wave,
             "total_s": time.perf_counter() - t_total,
-            "collectives": entry[1],
+            "collectives": entry[2],
         }
         hb = self._hash_balance(P_new)
         if hb is not None:
@@ -504,11 +550,6 @@ class _ElasticBase:
         rt.on_migration(stats)   # SimRuntime charges the wire model here
         self.migrations.append(stats)
         return stats
-
-    @staticmethod
-    def _count_all_to_all(jitted, args) -> int:
-        from ..analysis import count_all_to_all
-        return count_all_to_all(jitted, args)
 
     def _hash_balance(self, P_new: int) -> Optional[dict]:
         """Paper-fidelity report: what the consistent-hashing layer
@@ -587,6 +628,9 @@ class _ElasticBase:
 
     def _build_migration(self, mesh, P_old, P_new):
         raise NotImplementedError
+
+    def _migration_name(self, P_old: int, P_new: int) -> str:
+        return program_name(self._discipline, f"migrate_{P_old}to{P_new}")
 
     def _unpack(self, state):
         raise NotImplementedError
@@ -693,7 +737,8 @@ class _MultiWindowElastic(_ElasticBase):
             return firsts, lasts, nsv, nsf, moved, lost
 
         specs = (P(), P(), P(axis), P(axis))
-        wrapped = shard_map(body, mesh=mesh, in_specs=specs,
+        wrapped = shard_map(named(body, self._migration_name(P_old, P_new)),
+                            mesh=mesh, in_specs=specs,
                             out_specs=specs + (P(), P()))
         return jax.jit(wrapped, donate_argnums=(2, 3))
 
@@ -707,6 +752,8 @@ class ElasticDeviceQueue(_ElasticBase):
     store between bursts.  See the module docstring for the mechanism."""
 
     _kind = "queue"
+    _discipline = "fifo"
+    _ovf_out = 4            # (pos, matched, deq_vals, deq_ok, overflow)
     _pad_fill = (0, False)
     _sharded_keys = frozenset({"store_vals", "store_full"})
 
@@ -738,23 +785,13 @@ class ElasticDeviceQueue(_ElasticBase):
         """One wave on the current mesh; state is threaded internally.
         Returns (positions, matched, deq_vals, deq_ok, overflow); raises
         :class:`~.errors.QueueOverflowError` when the wave overflowed."""
-        with self._burst_span(1):
-            self.state, pos, m, dv, dok, ovf = self.inner.step(
-                self.state, self._place(is_enq), self._place(valid),
-                self._place(payload))
-        self._check_overflow(ovf)
-        return pos, m, dv, dok, ovf
+        return self._burst(self.inner.step, (is_enq, valid, payload), 0)
 
     def run_waves(self, is_enq, valid, payload):
         """K pre-staged waves in one dispatch (shapes [K, n_shards * L]).
         Raises :class:`~.errors.QueueOverflowError` on overflow."""
-        is_enq = self._place(is_enq, lead=1)
-        with self._burst_span(is_enq.shape[0]):
-            self.state, pos, m, dv, dok, ovf = self.inner.run_waves(
-                self.state, is_enq, self._place(valid, lead=1),
-                self._place(payload, lead=1))
-        self._check_overflow(ovf)
-        return pos, m, dv, dok, ovf
+        return self._burst(self.inner.run_waves, (is_enq, valid, payload),
+                           1)
 
     @property
     def size(self) -> int:
@@ -811,7 +848,8 @@ class ElasticDeviceQueue(_ElasticBase):
             return first, last, nsv, nsf, moved, lost
 
         specs = (P(), P(), P(axis), P(axis))
-        wrapped = shard_map(body, mesh=mesh, in_specs=specs,
+        wrapped = shard_map(named(body, self._migration_name(P_old, P_new)),
+                            mesh=mesh, in_specs=specs,
                             out_specs=specs + (P(), P()))
         return jax.jit(wrapped, donate_argnums=(2, 3))
 
@@ -826,6 +864,8 @@ class ElasticDeviceStack(_ElasticBase):
     collision-free on the receiving side."""
 
     _kind = "stack"
+    _discipline = "lifo"
+    _ovf_out = 4            # (pos, matched, pop_vals, pop_ok, overflow)
     _pad_fill = (0, -1)  # vals pad 0, tickets pad -1 (= empty)
     _sharded_keys = frozenset({"vals", "ticks"})
 
@@ -863,23 +903,13 @@ class ElasticDeviceStack(_ElasticBase):
         """One wave on the current mesh; state is threaded internally.
         Returns (positions, matched, pop_vals, pop_ok, overflow); raises
         :class:`~.errors.QueueOverflowError` when the wave overflowed."""
-        with self._burst_span(1):
-            self.state, pos, m, pv, pok, ovf = self.inner.step(
-                self.state, self._place(is_push), self._place(valid),
-                self._place(payload))
-        self._check_overflow(ovf)
-        return pos, m, pv, pok, ovf
+        return self._burst(self.inner.step, (is_push, valid, payload), 0)
 
     def run_waves(self, is_push, valid, payload):
         """K pre-staged waves in one dispatch (shapes [K, n_shards * L]).
         Raises :class:`~.errors.QueueOverflowError` on overflow."""
-        is_push = self._place(is_push, lead=1)
-        with self._burst_span(is_push.shape[0]):
-            self.state, pos, m, pv, pok, ovf = self.inner.run_waves(
-                self.state, is_push, self._place(valid, lead=1),
-                self._place(payload, lead=1))
-        self._check_overflow(ovf)
-        return pos, m, pv, pok, ovf
+        return self._burst(self.inner.run_waves, (is_push, valid, payload),
+                           1)
 
     @property
     def size(self) -> int:
@@ -949,6 +979,7 @@ class ElasticDeviceStack(_ElasticBase):
             return last, ticket, nsv[None], nstk[None], moved, lost
 
         specs = (P(), P(), P(axis), P(axis))
-        wrapped = shard_map(body, mesh=mesh, in_specs=specs,
+        wrapped = shard_map(named(body, self._migration_name(P_old, P_new)),
+                            mesh=mesh, in_specs=specs,
                             out_specs=specs + (P(), P()))
         return jax.jit(wrapped, donate_argnums=(2, 3))

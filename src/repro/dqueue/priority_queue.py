@@ -72,6 +72,7 @@ class PriorityDiscipline(Discipline):
     """Skeap constant-priority order: P masked min-plus scans + in-wave
     batch-DeleteMin dequeue resolution over the shared dense-ring store."""
 
+    name = "prio"
     n_ops = 4           # (is_enq, valid, prio, payload)
     n_disp_outs = 3     # (tier, pos, matched)
     n_aux = 1           # n_relaxed
@@ -258,6 +259,8 @@ class ElasticDevicePriorityQueue(_MultiWindowElastic):
     manifests record the per-tier layout so cold starts can reshard."""
 
     _kind = "pqueue"
+    _discipline = "prio"
+    _ovf_out = 5            # after (tier|bucket, pos, matched, deq_vals, deq_ok)
     _pad_fill = (0, False)
     _sharded_keys = frozenset({"store_vals", "store_full"})
 
@@ -298,23 +301,14 @@ class ElasticDevicePriorityQueue(_MultiWindowElastic):
         Returns (tier, pos, matched, deq_vals, deq_ok, overflow,
         n_relaxed); raises :class:`~.errors.QueueOverflowError` when the
         wave overflowed a tier window."""
-        with self._burst_span(1):
-            self.state, *out = self.inner.step(
-                self.state, self._place(is_enq), self._place(valid),
-                self._place(prio), self._place(payload))
-        self._check_overflow(out[5])
-        return tuple(out)
+        return self._burst(self.inner.step, (is_enq, valid, prio, payload),
+                           0)
 
     def run_waves(self, is_enq, valid, prio, payload):
         """K pre-staged waves in one dispatch (shapes [K, n_shards * L]).
         Raises :class:`~.errors.QueueOverflowError` on tier overflow."""
-        is_enq = self._place(is_enq, lead=1)
-        with self._burst_span(is_enq.shape[0]):
-            self.state, *out = self.inner.run_waves(
-                self.state, is_enq, self._place(valid, lead=1),
-                self._place(prio, lead=1), self._place(payload, lead=1))
-        self._check_overflow(out[5])
-        return tuple(out)
+        return self._burst(self.inner.run_waves,
+                           (is_enq, valid, prio, payload), 1)
 
     # -------------------------------------------------------- migration ----
     def _unpack(self, state):

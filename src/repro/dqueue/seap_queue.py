@@ -82,6 +82,7 @@ class SeapDiscipline(Discipline):
     min-plus scans + boundary-ordered batch-DeleteMin, over the shared
     dense-ring store, with the in-wave split/merge directory rebalance."""
 
+    name = "seap"
     n_ops = 4           # (is_enq, valid, key, payload)
     n_disp_outs = 3     # (bucket, pos, matched)
     n_aux = 1           # n_active (directory size after the rebalance)
@@ -301,6 +302,8 @@ class ElasticDeviceSeapQueue(_MultiWindowElastic):
     manifests record the bucket layout so cold starts can reshard."""
 
     _kind = "squeue"
+    _discipline = "seap"
+    _ovf_out = 5            # after (tier|bucket, pos, matched, deq_vals, deq_ok)
     _pad_fill = (0, False)
     _sharded_keys = frozenset({"store_vals", "store_full"})
 
@@ -345,23 +348,13 @@ class ElasticDeviceSeapQueue(_MultiWindowElastic):
         Returns (bucket, pos, matched, deq_vals, deq_ok, overflow,
         n_active); raises :class:`~.errors.QueueOverflowError` when the
         wave overflowed a bucket window."""
-        with self._burst_span(1):
-            self.state, *out = self.inner.step(
-                self.state, self._place(is_enq), self._place(valid),
-                self._place(key), self._place(payload))
-        self._check_overflow(out[5])
-        return tuple(out)
+        return self._burst(self.inner.step, (is_enq, valid, key, payload), 0)
 
     def run_waves(self, is_enq, valid, key, payload):
         """K pre-staged waves in one dispatch (shapes [K, n_shards * L]).
         Raises :class:`~.errors.QueueOverflowError` on bucket overflow."""
-        is_enq = self._place(is_enq, lead=1)
-        with self._burst_span(is_enq.shape[0]):
-            self.state, *out = self.inner.run_waves(
-                self.state, is_enq, self._place(valid, lead=1),
-                self._place(key, lead=1), self._place(payload, lead=1))
-        self._check_overflow(out[5])
-        return tuple(out)
+        return self._burst(self.inner.run_waves,
+                           (is_enq, valid, key, payload), 1)
 
     @property
     def n_active(self) -> int:

@@ -83,6 +83,34 @@ TAG_INACTIVE = 0
 TAG_PUT = 1
 TAG_GET = 2
 
+# The ``jax.named_scope`` of each phase of a wave: every operation of the
+# wave bodies sits under one of them, so a compiled program's instructions
+# (and a device trace's ops) can be put to the phase that issued them.
+WAVE_PHASES = ("dispatch", "pack", "exchange", "commit", "reply", "merge",
+               "telemetry")
+
+
+def program_name(discipline: str, entry: str) -> str:
+    """The stable name of one jitted program (its module compiles as
+    ``jit_<name>``): ``skueue_fifo_waves``, ``skueue_lifo_step``,
+    ``skueue_fifo_migrate_4to3``."""
+    return f"skueue_{discipline}_{entry}"
+
+
+def named(fn, name: str):
+    """``fn`` renamed to ``name`` before ``shard_map``/``jax.jit`` wrap it,
+    so that the program's module, its compile events and its device-trace
+    module all carry the name."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
+def _abstract(x):
+    """Shape, dtype and (for a committed array) sharding of an argument:
+    enough to lower a program again to the executable jax already holds."""
+    sharding = x.sharding if getattr(x, "committed", False) else None
+    return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
 
 # ------------------------------------------------- occupancy buckets -------
 def bucket_ladder(L: int) -> tuple:
@@ -185,7 +213,8 @@ class Dispatch(NamedTuple):
 class Discipline:
     """Position-assignment + store-rewrite plug-in for :class:`WaveEngine`.
 
-    Subclasses define class attributes ``n_ops`` (op input arrays per
+    Subclasses define class attributes ``name`` (the discipline's part of
+    its programs' names), ``n_ops`` (op input arrays per
     wave), ``n_disp_outs`` (dispatch-time per-op outputs), ``n_aux``
     (replicated per-wave extras) and ``extra_fill`` (sentinel values for
     extra request columns), instance attributes ``W`` / ``junk`` /
@@ -193,6 +222,7 @@ class Discipline:
     shard_map on per-shard local views.
     """
 
+    name: str = "wave"
     n_ops: int = 3
     n_disp_outs: int = 2
     n_aux: int = 0
@@ -274,6 +304,9 @@ class WaveEngine:
         self.metrics_ring = int(metrics_ring)
         self._mstate = self.init_metrics_state() if self.metrics else None
         self._seq0 = 0  # waves drained-and-reset before the current ring
+        # (entry, op shapes) -> abstract arguments of each program
+        # dispatched, for :meth:`phase_tables`
+        self._dispatched: dict = {}
         self._step = self._build_step()
         self._run_waves = self._build_run_waves()
 
@@ -332,16 +365,24 @@ class WaveEngine:
         ring; telemetry is dispatch-time arithmetic only)."""
         disc = self.disc
         carry, store = disc.split(state)
-        d = disc.dispatch(carry, ops)
+        with jax.named_scope("dispatch"):
+            d = disc.dispatch(carry, ops)
         if m is not None:
-            m = record_row(m, self._metric_row(d, ops, m.count))
-        recv = lax.all_to_all(self._pack_request(d), self.axis, 0, 0,
-                              tiled=True)
-        store, reply, c_ovf = disc.commit(store, recv)
-        back = lax.all_to_all(reply, self.axis, 0, 0, tiled=True)
-        dv, dok = self._extract_reply(back, d.owner, d.wants_reply)
-        ovf = jnp.logical_or(d.overflow, c_ovf)
-        merged = disc.merge(d.carry, store)
+            with jax.named_scope("telemetry"):
+                m = record_row(m, self._metric_row(d, ops, m.count))
+        with jax.named_scope("pack"):
+            req = self._pack_request(d)
+        with jax.named_scope("exchange"):
+            recv = lax.all_to_all(req, self.axis, 0, 0, tiled=True)
+        with jax.named_scope("commit"):
+            store, reply, c_ovf = disc.commit(store, recv)
+        with jax.named_scope("exchange"):
+            back = lax.all_to_all(reply, self.axis, 0, 0, tiled=True)
+        with jax.named_scope("reply"):
+            dv, dok = self._extract_reply(back, d.owner, d.wants_reply)
+            ovf = jnp.logical_or(d.overflow, c_ovf)
+        with jax.named_scope("merge"):
+            merged = disc.merge(d.carry, store)
         outs = d.outs + (dv, dok, ovf) + d.aux
         if m is None:
             return merged, outs
@@ -369,9 +410,11 @@ class WaveEngine:
         n, L = self.n_shards, ops[0].shape[1]
         C_req = 2 + len(disc.extra_fill) + disc.W
         carry0, store0 = disc.split(state)
-        prime = {
+        with jax.named_scope("pack"):
             # an all-sentinel in-flight buffer commits as a no-op
-            "recv": jnp.tile(self._req_fill()[None, None, :], (n, L, 1)),
+            recv0 = jnp.tile(self._req_fill()[None, None, :], (n, L, 1))
+        prime = {
+            "recv": recv0,
             "owner": jnp.full((L,), -1, jnp.int32),
             "wants": jnp.zeros((L,), bool),
             "outs": disc.zero_outs(L),
@@ -385,18 +428,26 @@ class WaveEngine:
                 mm = None
             else:
                 carry, store, infl, mm = c
-            d = disc.dispatch(carry, xs)                  # wave k
+            with jax.named_scope("dispatch"):
+                d = disc.dispatch(carry, xs)              # wave k
             if mm is not None:
-                mm = record_row(mm, self._metric_row(d, xs, mm.count))
-            store, reply, c_ovf = disc.commit(store, infl["recv"])  # k-1
-            fused = jnp.concatenate([self._pack_request(d), reply], axis=-1)
-            out = lax.all_to_all(fused, self.axis, 0, 0, tiled=True)
-            dv, dok = self._extract_reply(out[..., C_req:], infl["owner"],
-                                          infl["wants"])
-            emitted = (infl["outs"]
-                       + (dv, dok, jnp.logical_or(infl["ovf"], c_ovf))
-                       + infl["aux"])
-            infl = {"recv": out[..., :C_req], "owner": d.owner,
+                with jax.named_scope("telemetry"):
+                    mm = record_row(mm, self._metric_row(d, xs, mm.count))
+            with jax.named_scope("commit"):               # wave k-1
+                store, reply, c_ovf = disc.commit(store, infl["recv"])
+            with jax.named_scope("pack"):
+                fused = jnp.concatenate([self._pack_request(d), reply],
+                                        axis=-1)
+            with jax.named_scope("exchange"):
+                out = lax.all_to_all(fused, self.axis, 0, 0, tiled=True)
+                recv = out[..., :C_req]
+            with jax.named_scope("reply"):
+                dv, dok = self._extract_reply(out[..., C_req:],
+                                              infl["owner"], infl["wants"])
+                emitted = (infl["outs"]
+                           + (dv, dok, jnp.logical_or(infl["ovf"], c_ovf))
+                           + infl["aux"])
+            infl = {"recv": recv, "owner": d.owner,
                     "wants": d.wants_reply, "outs": d.outs,
                     "ovf": jnp.asarray(d.overflow), "aux": d.aux}
             nc = ((d.carry, store, infl) if m is None
@@ -411,16 +462,21 @@ class WaveEngine:
         else:
             carry, store, infl, m = final
         # epilogue: commit the last in-flight wave, reply-only collective
-        store, reply, c_ovf = disc.commit(store, infl["recv"])
-        back = lax.all_to_all(reply, self.axis, 0, 0, tiled=True)
-        dv, dok = self._extract_reply(back, infl["owner"], infl["wants"])
-        last = (infl["outs"]
-                + (dv, dok, jnp.logical_or(infl["ovf"], c_ovf))
-                + infl["aux"])
-        # drop the priming wave's garbage row, append the drained last wave
-        outs = tuple(jnp.concatenate([s[1:], l[None]], axis=0)
-                     for s, l in zip(stacked, last))
-        merged = disc.merge(carry, store)
+        with jax.named_scope("commit"):
+            store, reply, c_ovf = disc.commit(store, infl["recv"])
+        with jax.named_scope("exchange"):
+            back = lax.all_to_all(reply, self.axis, 0, 0, tiled=True)
+        with jax.named_scope("reply"):
+            dv, dok = self._extract_reply(back, infl["owner"], infl["wants"])
+            last = (infl["outs"]
+                    + (dv, dok, jnp.logical_or(infl["ovf"], c_ovf))
+                    + infl["aux"])
+            # drop the priming wave's garbage row, append the drained last
+            # wave
+            outs = tuple(jnp.concatenate([s[1:], l[None]], axis=0)
+                         for s, l in zip(stacked, last))
+        with jax.named_scope("merge"):
+            merged = disc.merge(carry, store)
         if m is None:
             return (merged,) + outs
         return ((merged, m),) + outs
@@ -450,7 +506,7 @@ class WaveEngine:
         in_state = ((self.disc.state_specs, self._m_specs())
                     if self.metrics else self.disc.state_specs)
         wrapped = shard_map(
-            fn, mesh=self.mesh,
+            named(fn, program_name(self.disc.name, "step")), mesh=self.mesh,
             in_specs=(in_state,) + (P(self.axis),) * self.disc.n_ops,
             out_specs=self._out_specs())
         return jax.jit(wrapped, donate_argnums=(0,))
@@ -468,7 +524,7 @@ class WaveEngine:
         in_state = ((self.disc.state_specs, self._m_specs())
                     if self.metrics else self.disc.state_specs)
         wrapped = shard_map(
-            fn, mesh=self.mesh,
+            named(fn, program_name(self.disc.name, "waves")), mesh=self.mesh,
             in_specs=(in_state,) + (P(None, self.axis),) * self.disc.n_ops,
             out_specs=self._out_specs(multi=True))
         return jax.jit(wrapped, donate_argnums=(0,))
@@ -477,19 +533,37 @@ class WaveEngine:
         """One wave.  The state argument is DONATED.  With metrics on,
         the engine-owned telemetry ring rides the donated tuple
         internally — same external signature either way."""
-        if not self.metrics:
-            return self._step(state, *ops)
-        out = self._step((state, self._mstate), *ops)
-        st, self._mstate = out[0]
-        return (st,) + tuple(out[1:])
+        return self._call("step", self._step, state, ops)
 
     def run_waves(self, state, *ops):
         """K pre-staged waves in ONE device dispatch (state DONATED)."""
+        return self._call("waves", self._run_waves, state, ops)
+
+    def _call(self, entry: str, prog, state, ops):
+        arg = (state, self._mstate) if self.metrics else state
+        key = (entry,) + tuple(o.shape for o in ops)
+        if key not in self._dispatched:
+            self._dispatched[key] = jax.tree.map(_abstract, (arg,) + ops)
+        out = prog(arg, *ops)
         if not self.metrics:
-            return self._run_waves(state, *ops)
-        out = self._run_waves((state, self._mstate), *ops)
+            return out
         st, self._mstate = out[0]
         return (st,) + tuple(out[1:])
+
+    def phase_tables(self) -> list:
+        """For each program this engine has dispatched (one per entry
+        point, burst length and width), the phase of each instruction of
+        its compiled text: ``{instruction name: phase}`` over
+        :data:`WAVE_PHASES`.  Instruction names are those a device trace
+        gives its ops.  The executables come from jax's cache of the
+        programs already run; nothing compiles."""
+        from ..analysis.hlo import scope_table
+        tables = []
+        for key, args in self._dispatched.items():
+            prog = self._run_waves if key[0] == "waves" else self._step
+            text = prog.lower(*args).compile().as_text()
+            tables.append(scope_table(text, WAVE_PHASES))
+        return tables
 
     # ----------------------------------------------------- metrics drain ---
     def init_metrics_state(self) -> MetricsState:

@@ -380,7 +380,8 @@ def run_membership(*, n_from: int, records: int, W: int, L: int, K: int,
                                    f"{backlog} -> {q.size}")
             stats["migrations"].append(
                 {k: mig[k] for k in ("kind", "P_from", "P_to", "moved",
-                                     "bytes_moved", "wave_s", "total_s")}
+                                     "bytes_moved", "compile_s", "wave_s",
+                                     "total_s")}
                 | {"placement": store_placement(q)})
 
     _drive(q, "fifo", Reference("fifo", q, L), Traffic("fifo", seed, W),

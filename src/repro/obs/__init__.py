@@ -6,8 +6,9 @@ Four layers, one package:
    :class:`~repro.dqueue.wave_engine.WaveEngine` fills with ZERO extra
    collectives (every row field is arithmetic on values the wave already
    materializes); drained to host only at burst boundaries.
-2. ``obs.trace``    — wall-clock timers (alpa style) and a span API with
-   ``jax.profiler`` annotations and Chrome-trace/perfetto JSON export.
+2. ``obs.trace``    — a span API with ``jax.profiler`` annotations, a
+   per-name summary and Chrome-trace/perfetto JSON export, stamped on
+   the profiler's clock.
 3. ``obs.recorder`` — the flight recorder: the last K wave summaries,
    attached to :class:`~repro.dqueue.errors.QueueOverflowError` as the
    occupancy trajectory that led to the failure.
@@ -24,7 +25,7 @@ from typing import Any
 __all__ = [
     "METRIC_HEAD", "MetricsState", "init_metrics_state", "record_row",
     "drain", "row_width",
-    "Timer", "Timers", "timers", "Tracer", "tracer", "span",
+    "Tracer", "tracer", "span",
     "FlightRecorder",
     "to_json", "to_prometheus",
 ]
@@ -33,7 +34,6 @@ _LAZY = {
     "METRIC_HEAD": "device", "MetricsState": "device",
     "init_metrics_state": "device", "record_row": "device",
     "drain": "device", "row_width": "device",
-    "Timer": "trace", "Timers": "trace", "timers": "trace",
     "Tracer": "trace", "tracer": "trace", "span": "trace",
     "FlightRecorder": "recorder",
     "to_json": "export", "to_prometheus": "export",

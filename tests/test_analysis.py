@@ -222,6 +222,35 @@ def test_compilation_tracker_counts_only_fresh_compiles():
     assert warm.count == 0, warm.count
 
 
+def test_compilation_tracker_names_the_program_it_compiled():
+    """Compiles are kept by program name, for the process and for the
+    tracker; reading a dispatched program's phase table back from jax's
+    cache compiles nothing."""
+    import numpy as np
+
+    from repro.analysis import CompilationTracker
+    from repro.dqueue import ElasticDeviceQueue
+
+    q = ElasticDeviceQueue(1, cap=32, payload_width=3, ops_per_shard=4)
+    e = np.ones((5, 4), bool)
+    enq = np.arange(20).reshape(5, 4) % 2 == 0
+    pw = np.zeros((5, 4, 3), np.int32)
+    before = CompilationTracker.by_program().get(
+        "jit(skueue_fifo_waves)", {"compiles": 0, "compile_s": 0.0})
+    with CompilationTracker() as cold:
+        q.run_waves(enq, e, pw)
+    assert "jit(skueue_fifo_waves)" in cold.programs
+    after = CompilationTracker.by_program()["jit(skueue_fifo_waves)"]
+    assert after["compiles"] == before["compiles"] + 1
+    assert after["compile_s"] > before["compile_s"]
+    with CompilationTracker() as warm:
+        q.run_waves(enq, e, pw)
+        tables = q.wave_phases()
+    assert warm.count == 0 and warm.programs == []
+    assert len(tables) == 1
+    assert {"dispatch", "commit", "reply"} <= set(tables[0].values())
+
+
 def test_budget_check_reports_undeclared_collectives():
     from repro.analysis import CollectiveBudget, check_budget
 

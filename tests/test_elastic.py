@@ -317,3 +317,41 @@ def test_serve_engine_resize_8dev():
     every request served, FIFO admission preserved across JOIN and LEAVE."""
     out = run_multidev(SERVE_RESIZE, n_dev=8)
     assert "OK serve resize" in out
+
+
+MIGRATION_COMPILE = r"""
+import numpy as np
+from repro.analysis import CompilationTracker
+from repro.dqueue import ElasticDeviceQueue
+from repro.obs.trace import tracer
+
+q = ElasticDeviceQueue(1, cap=64, payload_width=2, ops_per_shard=4)
+e = np.ones((2, 4), bool)
+q.run_waves(e, e, np.zeros((2, 4, 2), np.int32))
+g1, s1 = q.grow(1), q.shrink([1])
+with CompilationTracker() as again:
+    g2 = q.grow(1)
+assert again.count == 0, again.programs
+assert g1["compile_s"] > 0 and g2["compile_s"] < g1["compile_s"] / 10
+assert g1["moved"] == g2["moved"] == 8 and s1["moved"] == 8
+progs = CompilationTracker.by_program()
+assert progs["jit(skueue_fifo_migrate_1to2)"]["compiles"] == 1
+assert progs["jit(skueue_fifo_migrate_2to1)"]["compiles"] == 1
+spans = [(ev["name"], ev["args"].get("parent")) for ev in tracer.events()
+         if ev["name"].startswith("migration:")]
+assert spans == [
+    ("migration:stage", "migration:grow"),
+    ("migration:compile", "migration:grow"), ("migration:grow", None),
+    ("migration:compile", "migration:shrink"),
+    ("migration:land", "migration:shrink"), ("migration:shrink", None),
+    ("migration:stage", "migration:grow"), ("migration:grow", None)], spans
+print("OK")
+"""
+
+
+def test_migration_compiles_ahead_of_its_timed_wave_2dev():
+    """A migration program is compiled once, under its own span and
+    stats entry, ahead of the wave that ``wave_s`` times; a second use of
+    it compiles nothing; host staging and landing have spans."""
+    out = run_multidev(MIGRATION_COMPILE, n_dev=2)
+    assert "OK" in out

@@ -1,7 +1,8 @@
 """PR 7 Wavescope: the observability package.
 
 Device metrics ring (record/drain semantics, wraparound, additive vs
-replicated fields), host tracer (spans, Chrome-trace export, timers),
+replicated fields), host tracer (spans, Chrome-trace export, per-name
+summary, the profiler's clock, the elastic burst spans),
 flight recorder bounds, exposition (JSON / Prometheus), the
 ``python -m repro.obs --smoke`` CLI, and ``ServeEngine.metrics()``.
 """
@@ -78,7 +79,7 @@ def test_engine_drain_reset_advances_seq_base():
 
 
 # ---------------------------------------------------------------------------
-# host tracer + timers
+# host tracer
 # ---------------------------------------------------------------------------
 def test_tracer_spans_and_chrome_export(tmp_path):
     from repro.obs.trace import Tracer
@@ -114,16 +115,112 @@ def test_tracer_ring_is_bounded():
 
 
 def test_timers_accumulate():
-    from repro.obs.trace import Timers
+    """The tracer's per-name summary: count, total and last duration of
+    every span it recorded, kept when the ring forgets, reset by clear."""
+    from repro.obs.trace import Tracer
 
-    tm = Timers()
+    tr = Tracer(max_events=2, annotate=False)
     for _ in range(3):
-        with tm("step"):
+        with tr.span("step"):
             pass
-    assert tm("step").count == 3
-    assert tm("step").elapsed("sum") >= tm("step").elapsed("max") >= 0
-    assert set(tm.names()) == {"step"}
-    assert "step" in tm.report()
+    with tr.span("other"):
+        pass
+    summ = tr.summary()
+    assert set(summ) == {"step", "other"}
+    assert summ["step"]["count"] == 3 and summ["other"]["count"] == 1
+    step = [e["dur"] * 1e-6 for e in tr.events() if e["name"] == "step"]
+    assert summ["step"]["last_s"] == pytest.approx(step[-1])
+    assert summ["step"]["total_s"] >= summ["step"]["last_s"] >= 0
+    tr.clear()
+    assert tr.summary() == {}
+
+
+def test_tracer_records_parent_and_stamps_the_wall_clock():
+    import time
+    from repro.obs.trace import Tracer
+
+    tr = Tracer(annotate=False)
+    before = time.time_ns() / 1e3
+    with tr.span("outer", burst=7):
+        with tr.span("inner"):
+            pass
+    inner, outer = tr.events()
+    assert inner["args"] == {"parent": "outer"}
+    assert outer["args"] == {"burst": 7}
+    assert before <= outer["ts"] <= inner["ts"]
+    assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
+    assert outer["ts"] + outer["dur"] <= time.time_ns() / 1e3
+
+
+def _xplane_host_spans(log_dir, prefix):
+    """Host events whose name starts with ``prefix`` in the profile under
+    ``log_dir``, as ``(start_ns, end_ns, name, stats)`` on the wall
+    clock (a profile's times are relative to its start)."""
+    import glob
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(str(log_dir), "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    pd = ProfileData.from_file(path)
+    t0 = [dict(p.stats)["profile_start_time"] for p in pd.planes
+          if "profile_start_time" in dict(p.stats)][0]
+    return [(t0 + ev.start_ns, t0 + ev.start_ns + ev.duration_ns, ev.name,
+             dict(ev.stats))
+            for p in pd.planes if p.name.startswith("/host:")
+            for line in p.lines for ev in line.events
+            if ev.name.startswith(prefix)]
+
+
+def test_queue_spans_share_the_profilers_clock(tmp_path):
+    """A burst's spans recorded in the ring and in a profile: the same
+    names and stats, and the same start and end within 200 us."""
+    import jax
+    from repro.dqueue import ElasticDeviceQueue
+    from repro.obs.trace import tracer
+
+    q = ElasticDeviceQueue(1, cap=64, payload_width=2, ops_per_shard=8)
+    e = np.ones((2, 8), bool)
+    pw = np.zeros((2, 8, 2), np.int32)
+    q.run_waves(e, e, pw)                     # compile outside the profile
+    tracer.clear()
+    with jax.profiler.trace(str(tmp_path)):
+        q.run_waves(e, e, pw)
+    ring = {ev["name"]: ev for ev in tracer.events()}
+    xp = _xplane_host_spans(tmp_path, "queue:")
+    assert {n for _, _, n, _ in xp} == {"queue:burst", "queue:launch",
+                                        "queue:overflow_wait"} == set(ring)
+    for s, e_, name, stats in xp:
+        ev = ring[name]
+        assert abs(ev["ts"] * 1e3 - s) < 200e3, name
+        assert abs((ev["ts"] + ev["dur"]) * 1e3 - e_) < 200e3, name
+        assert stats["burst"] == ev["args"]["burst"]
+
+
+@pytest.mark.parametrize("metrics", [False, True])
+def test_burst_spans_share_a_burst_id_and_parent(metrics):
+    from repro.dqueue import ElasticDeviceQueue
+    from repro.obs.trace import tracer
+
+    q = ElasticDeviceQueue(1, cap=64, payload_width=2, ops_per_shard=8,
+                           metrics=metrics)
+    e = np.ones((3, 8), bool)
+    pw = np.zeros((3, 8, 2), np.int32)
+    tracer.clear()
+    q.run_waves(e, e, pw)
+    q.step(e[0], e[0], pw[0])
+    evs = tracer.events()
+    inner = ["queue:launch"] + (["queue:telemetry_drain"] if metrics
+                                else []) + ["queue:overflow_wait"]
+    assert [ev["name"] for ev in evs] == 2 * (inner + ["queue:burst"])
+    for burst, group in enumerate((evs[:len(inner) + 1],
+                                   evs[len(inner) + 1:])):
+        *kids, parent = group
+        assert parent["args"]["burst"] == burst
+        assert parent["args"]["K"] == (3, 1)[burst]
+        for ev in kids:
+            assert ev["args"] == {"burst": burst, "parent": "queue:burst"}
+            assert parent["ts"] <= ev["ts"]
+            assert ev["ts"] + ev["dur"] <= parent["ts"] + parent["dur"]
+    assert tracer.summary()["queue:launch"]["count"] == 2
 
 
 # ---------------------------------------------------------------------------

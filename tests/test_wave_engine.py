@@ -540,3 +540,57 @@ def test_flight_recorder_trajectory_on_overflow():
         assert r["headroom"] == 8 - occ
     # the failing wave is the last summary, already past capacity's edge
     assert occ + 3 > 8 or occ > 8
+
+
+# --------------------------------------------------------------------------
+# Stable program names and wave-phase scopes: every program compiles as
+# jit_skueue_<discipline>_<entry>, and every scatter and gather of a wave
+# sits under one of the phase scopes a device trace is split by.
+# --------------------------------------------------------------------------
+import pytest  # noqa: E402
+
+
+@pytest.mark.parametrize("kind", ["fifo", "lifo", "prio", "seap"])
+def test_programs_are_named_and_every_scatter_gather_is_phased(kind):
+    import re
+    import jax
+    import jax.numpy as jnp
+    from repro.analysis.hlo import parse_hlo
+    from repro.compat import make_mesh
+    from repro.dqueue import (DevicePriorityQueue, DeviceQueue,
+                              DeviceSeapQueue, DeviceStack)
+    from repro.dqueue.wave_engine import WAVE_PHASES
+
+    mesh = make_mesh((1,), ("data",))
+    kw = dict(cap=16, payload_width=2, ops_per_shard=4)
+    make = {"fifo": lambda **k: DeviceQueue(mesh, "data", **kw, **k),
+            "lifo": lambda **k: DeviceStack(mesh, "data", **kw, **k),
+            "prio": lambda **k: DevicePriorityQueue(mesh, "data", n_prios=2,
+                                                    **kw, **k),
+            "seap": lambda **k: DeviceSeapQueue(mesh, "data", n_buckets=4,
+                                                **kw, **k)}[kind]
+    K, n = 3, 4
+    for opts in ({}, {"pipelined": False}, {"metrics": True}):
+        q = make(**opts)
+        eng = q.engine
+        state = jax.eval_shape(q.init_state)
+        if eng.metrics:
+            state = (state, jax.eval_shape(lambda: eng._mstate))
+        ops = [jax.ShapeDtypeStruct((K, n), jnp.bool_)] * 2
+        if eng.disc.n_ops == 4:
+            ops.append(jax.ShapeDtypeStruct((K, n), jnp.int32))
+        ops.append(jax.ShapeDtypeStruct((K, n, 2), jnp.int32))
+        one = [jax.ShapeDtypeStruct(o.shape[1:], o.dtype) for o in ops]
+        for entry, prog, args in (("waves", eng._run_waves, ops),
+                                  ("step", eng._step, one)):
+            text = prog.lower(state, *args).as_text(dialect="hlo",
+                                                    debug_info=True)
+            assert text.startswith(f"HloModule jit_skueue_{kind}_{entry},")
+            lines = text.splitlines()
+            moved = [op for op in parse_hlo(text).ops
+                     if op.opcode in ("scatter", "gather")]
+            assert moved, (kind, entry)
+            for op in moved:
+                meta = re.search(r'op_name="([^"]*)"', lines[op.line_no - 1])
+                assert meta and set(meta.group(1).split("/")) & set(
+                    WAVE_PHASES), (kind, entry, opts, op, meta)
