@@ -326,12 +326,22 @@ class WaveEngine:
                                  self._req_fill())
 
     def _extract_reply(self, back, owner, wants_reply):
-        """Local op j's reply sits at [owner[j], j] of the reply buffer."""
-        j = jnp.arange(owner.shape[0])
-        own_row = jnp.clip(owner, 0, self.n_shards - 1)
-        vals = jnp.where(wants_reply[:, None],
-                         back[own_row, j, 1:], jnp.int32(0))
-        ok = wants_reply & (back[own_row, j, 0] > 0)
+        """Local op j's reply sits at [owner[j], j] of the reply buffer.
+
+        Selected with a one-hot mask over the n reply rows (one term of
+        the sum is nonzero), not gathered per op: XLA:TPU lowers the
+        gather ``back[own_row, j]`` to a loop of L row slices a wave.
+        One shard has one row to take."""
+        n = self.n_shards
+        if n == 1:
+            mine = back[0]
+        else:
+            own_row = jnp.clip(owner, 0, n - 1)
+            mask = own_row[None, :] == jnp.arange(n)[:, None]
+            mine = jnp.sum(jnp.where(mask[..., None], back, 0), axis=0,
+                           dtype=back.dtype)
+        vals = jnp.where(wants_reply[:, None], mine[:, 1:], jnp.int32(0))
+        ok = wants_reply & (mine[:, 0] > 0)
         return vals, ok
 
     # ---------------------------------------------------------- metrics ----

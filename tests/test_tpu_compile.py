@@ -5,7 +5,8 @@ what Mosaic or XLA:TPU would reject on the chip: the three segscan sweeps
 at real widths with ``interpret=False``, and the four disciplines'
 ``run_waves`` bursts at deployment size (a 1 GiB store of 1 KiB records,
 8192 ops per shard, 8 waves) on one chip and on the 2x2 mesh.  Nothing
-runs, so results and times are out of their reach.
+runs, so results and times are out of their reach, but the compiled text
+shows which loops XLA:TPU made and the wave phase each one belongs to.
 
 The topology is described inside a module fixture: only the worker that
 runs this file loads the TPU library.
@@ -19,9 +20,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.analysis.hlo import count_op
+from repro.analysis.hlo import count_op, parse_hlo, scope_table
 from repro.dqueue import (DevicePriorityQueue, DeviceQueue, DeviceSeapQueue,
                           DeviceStack)
+from repro.dqueue.wave_engine import WAVE_PHASES
 from repro.kernels.segscan.kernel import (queue_scan_kernel,
                                           stack_scan_kernel,
                                           tiered_queue_scan_kernel)
@@ -102,8 +104,21 @@ def _wave_program(topo, kind, n_dev):
     return q, q._run_waves.lower(state, *ops).compile()
 
 
-def test_fifo_burst_compiles_on_one_chip(topo):
-    _, c = _wave_program(topo, "fifo", 1)
+@pytest.fixture(scope="module")
+def fifo_burst(topo):
+    """The FIFO burst compiled once per mesh size for every test that
+    reads it: n_dev -> compiled executable."""
+    done = {}
+
+    def get(n_dev):
+        if n_dev not in done:
+            done[n_dev] = _wave_program(topo, "fifo", n_dev)[1]
+        return done[n_dev]
+    return get
+
+
+def test_fifo_burst_compiles_on_one_chip(fifo_burst):
+    c = fifo_burst(1)
     mem = c.memory_analysis()
     assert mem.argument_size_in_bytes >= RECORDS * W * 4   # the 1 GiB store
     assert mem.alias_size_in_bytes >= RECORDS * W * 4      # donated in place
@@ -118,6 +133,18 @@ def test_fused_dispatch_burst_compiles_kernel(topo, compiled_kernels, kind):
     assert "tpu_custom_call" in c.as_text()
 
 
-def test_fifo_burst_on_2x2_mesh_has_two_all_to_alls(topo):
-    _, c = _wave_program(topo, "fifo", 4)
-    assert count_op(c.as_text(), "all-to-all") == 2
+def test_fifo_burst_on_2x2_mesh_has_two_all_to_alls(fifo_burst):
+    assert count_op(fifo_burst(4).as_text(), "all-to-all") == 2
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_fifo_burst_scan_is_its_only_loop(fifo_burst, n_dev):
+    """No loop in phase ``reply``: each op's reply is selected, not
+    gathered one row slice at a time."""
+    text = fifo_burst(n_dev).as_text()
+    phases = scope_table(text, WAVE_PHASES)
+    loops = [op.var.lstrip("%") for op in parse_hlo(text).ops
+             if op.opcode == "while"]
+    in_reply = [w for w in loops if phases.get(w) == "reply"]
+    assert in_reply == [], in_reply
+    assert len(loops) == 1, loops          # the burst's lax.scan

@@ -550,16 +550,18 @@ def test_flight_recorder_trajectory_on_overflow():
 import pytest  # noqa: E402
 
 
-@pytest.mark.parametrize("kind", ["fifo", "lifo", "prio", "seap"])
-def test_programs_are_named_and_every_scatter_gather_is_phased(kind):
-    import re
+KINDS = ["fifo", "lifo", "prio", "seap"]
+
+
+def _lowered_wave_programs(kind, entries=("waves", "step")):
+    """(entry, opts, HLO text with op_name metadata) of ``kind``'s jitted
+    wave programs at toy size on one shard: the pipelined, sequential and
+    metrics-on builds of each entry."""
     import jax
     import jax.numpy as jnp
-    from repro.analysis.hlo import parse_hlo
     from repro.compat import make_mesh
     from repro.dqueue import (DevicePriorityQueue, DeviceQueue,
                               DeviceSeapQueue, DeviceStack)
-    from repro.dqueue.wave_engine import WAVE_PHASES
 
     mesh = make_mesh((1,), ("data",))
     kw = dict(cap=16, payload_width=2, ops_per_shard=4)
@@ -583,14 +585,80 @@ def test_programs_are_named_and_every_scatter_gather_is_phased(kind):
         one = [jax.ShapeDtypeStruct(o.shape[1:], o.dtype) for o in ops]
         for entry, prog, args in (("waves", eng._run_waves, ops),
                                   ("step", eng._step, one)):
-            text = prog.lower(state, *args).as_text(dialect="hlo",
-                                                    debug_info=True)
-            assert text.startswith(f"HloModule jit_skueue_{kind}_{entry},")
-            lines = text.splitlines()
-            moved = [op for op in parse_hlo(text).ops
-                     if op.opcode in ("scatter", "gather")]
-            assert moved, (kind, entry)
-            for op in moved:
-                meta = re.search(r'op_name="([^"]*)"', lines[op.line_no - 1])
-                assert meta and set(meta.group(1).split("/")) & set(
-                    WAVE_PHASES), (kind, entry, opts, op, meta)
+            if entry in entries:
+                yield entry, opts, prog.lower(state, *args).as_text(
+                    dialect="hlo", debug_info=True)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_programs_are_named_and_every_scatter_gather_is_phased(kind):
+    import re
+    from repro.analysis.hlo import parse_hlo
+    from repro.dqueue.wave_engine import WAVE_PHASES
+
+    for entry, opts, text in _lowered_wave_programs(kind):
+        assert text.startswith(f"HloModule jit_skueue_{kind}_{entry},")
+        lines = text.splitlines()
+        moved = [op for op in parse_hlo(text).ops
+                 if op.opcode in ("scatter", "gather")]
+        assert moved, (kind, entry)
+        for op in moved:
+            meta = re.search(r'op_name="([^"]*)"', lines[op.line_no - 1])
+            assert meta and set(meta.group(1).split("/")) & set(
+                WAVE_PHASES), (kind, entry, opts, op, meta)
+
+
+# --------------------------------------------------------------------------
+# Reply extraction: each op's reply row is selected from the [n, L, 1+W]
+# reply buffer with a mask over the n rows, never gathered per op (XLA:TPU
+# lowers that gather to a loop of L row slices a wave).
+# --------------------------------------------------------------------------
+@pytest.mark.parametrize("W", [1, 256])
+@pytest.mark.parametrize("n", [1, 2, 4, 8])
+def test_extract_reply_is_bit_identical_to_the_row_gather(n, W):
+    from types import SimpleNamespace
+    import jax
+    import jax.numpy as jnp
+    from repro.analysis.hlo import count_op
+    from repro.dqueue.wave_engine import WaveEngine
+
+    L = 64
+    rng = np.random.default_rng(1000 * n + W)
+    back = rng.integers(-2**31, 2**31, (n, L, 1 + W), dtype=np.int64)
+    back[:, :8, 0] = 0                       # some ops' ok word is 0
+    back = back.astype(np.int32)
+    owner = rng.integers(-1, n + 2, L).astype(np.int32)  # -1, past n-1
+    owner[:3] = [-1, n, n + 1]
+    wants = rng.random(L) < 0.7
+
+    # the plain reference: the per-op gather the select replaced
+    j = np.arange(L)
+    own_row = np.clip(owner, 0, n - 1)
+    ref_vals = np.where(wants[:, None], back[own_row, j, 1:], 0)
+    ref_ok = wants & (back[own_row, j, 0] > 0)
+
+    extract = jax.jit(lambda b, o, w: WaveEngine._extract_reply(
+        SimpleNamespace(n_shards=n), b, o, w))
+    vals, ok = extract(jnp.asarray(back), jnp.asarray(owner),
+                       jnp.asarray(wants))
+    assert vals.dtype == jnp.int32 and ok.dtype == jnp.bool_
+    np.testing.assert_array_equal(np.asarray(vals), ref_vals)
+    np.testing.assert_array_equal(np.asarray(ok), ref_ok)
+    text = extract.lower(back, owner, wants).as_text(dialect="hlo")
+    assert count_op(text, "gather") == 0
+
+
+@pytest.mark.parametrize("entry", ["waves", "step"])
+@pytest.mark.parametrize("kind", KINDS)
+def test_no_gather_in_the_reply_phase(kind, entry):
+    import re
+    from repro.analysis.hlo import parse_hlo
+
+    for _, opts, text in _lowered_wave_programs(kind, (entry,)):
+        lines = text.splitlines()
+        for op in parse_hlo(text).ops:
+            if op.opcode != "gather":
+                continue
+            meta = re.search(r'op_name="([^"]*)"', lines[op.line_no - 1])
+            assert meta is None or "reply" not in meta.group(1).split("/"), (
+                kind, entry, opts, op, meta)
