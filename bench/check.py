@@ -45,15 +45,17 @@ class Log:
         self.bursts.append(burst)
 
 
-def check(log: Log, replay, pool) -> Dict[str, int]:
+def check(log: Log, replay, pool, void=frozenset()) -> Dict[str, int]:
     """Replay every burst through the reference.  ``wrong_ops`` counts the
     ops whose reply differs from the reference's or whose dequeued record
     is not the one the reference says it must be; ``wrong_replies`` and
-    ``wrong_records`` split it."""
+    ``wrong_records`` split it.  Every op of a burst whose index is in
+    ``void`` (answered by another membership than the schedule's) is
+    wrong as well."""
     slot_of = np.zeros(log.next_id, np.int64)
     key_of = np.zeros(log.next_id, np.int64)
     wrong_replies = wrong_records = wrong_ops = n_ops = 0
-    for b in log.bursts:
+    for i, b in enumerate(log.bursts):
         e = b.is_enq & b.valid
         ids = np.full(e.shape, -1, np.int64)
         ids[e] = b.first_id + np.arange(int(e.sum()))
@@ -76,6 +78,8 @@ def check(log: Log, replay, pool) -> Dict[str, int]:
         record_bad = b.digests[both[b.got["dok"]]] != expect
         wrong_records += int(record_bad.sum())
         bad[both] |= record_bad
+        if i in void:
+            bad |= b.valid
         wrong_ops += int(bad.sum())
     return {"wrong_ops": wrong_ops, "wrong_replies": wrong_replies,
             "wrong_records": wrong_records, "ops_checked": n_ops}

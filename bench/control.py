@@ -36,9 +36,10 @@ def reverse_within_waves(out: dict, ok, fields) -> None:
 
 
 class ReferenceStructure:
-    """The configuration's reference with ``run_waves``'s interface;
-    with ``control`` it hands each wave's successful dequeues their
-    answers in reverse order."""
+    """The configuration's reference with ``run_waves``'s interface and
+    the membership calls a schedule makes (its store is as long as the
+    cell's largest, so a change moves nothing); with ``control`` it hands
+    each wave's successful dequeues their answers in reverse order."""
 
     def __init__(self, cell, control: bool = True):
         cfg = cell.config
@@ -51,6 +52,27 @@ class ReferenceStructure:
         self.replay = cell.reference.Replay(self.tiers, self.window)
         self.control = control
         self.store = np.zeros((self.tiers, self.window, self.W), np.int32)
+        self.pool_ids = list(range(cell.chips))
+        self.device_ids = list(self.pool_ids)
+
+    @property
+    def n_shards(self) -> int:
+        return len(self.device_ids)
+
+    def _changed(self, kind: str, P_from: int) -> dict:
+        return {"kind": kind, "P_from": P_from, "P_to": self.n_shards}
+
+    def shrink_devices(self, dev_ids) -> dict:
+        P_from = self.n_shards
+        for i in dev_ids:
+            self.device_ids.remove(i)
+        return self._changed("shrink", P_from)
+
+    def grow(self, k: int = 1) -> dict:
+        P_from = self.n_shards
+        spare = [i for i in self.pool_ids if i not in self.device_ids]
+        self.device_ids += spare[:k]
+        return self._changed("grow", P_from)
 
     def bucket_widths(self) -> tuple:
         return tuple(sorted({max(1, self.L // 4), max(1, self.L // 2),
@@ -88,7 +110,7 @@ class ReferenceStructure:
 
 def main(argv=None) -> int:
     sys.path[:0] = [ROOT, os.path.join(ROOT, "src")]
-    from bench.harness import LIMITS, CellRun, Layout
+    from bench.harness import CellRun, Layout, limits_for
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
@@ -102,10 +124,12 @@ def main(argv=None) -> int:
             cell, control=not args.plain))
         run.setup()
         win = run.window(args.seconds)
+        run.drain()
         numbers = run.check()
         print(json.dumps({
             "workload": cell.name, "seed": seed, "control": not args.plain,
-            "correct": all(numbers[k] <= v for k, v in LIMITS.items()),
+            "correct": all(numbers[k] <= v
+                           for k, v in limits_for(run).items()),
             "window": win, **numbers}), flush=True)
     return 0
 

@@ -5,14 +5,17 @@ the configuration file its ``configs`` entry names, ``traffic/<mix>.json``,
 ``reference/<name>.py`` and ``metrics/<metric>.py``.  ``CellRun`` builds
 the configuration's structure through the program's elastic wrapper,
 prefills its backlog and warms every shape the window uses through the
-same ``run_waves`` program, drives the window as the mix says, and then
-checks every op against the reference.  ``main`` is the command.
+same ``run_waves`` program (and, where the mix has a membership schedule,
+every membership it will have), drives the window as the mix says
+(with a membership schedule, then drains the store at full width), and
+then checks every op against the reference.  ``main`` is the command.
 """
 from __future__ import annotations
 
 import contextlib
 import importlib.util
 import json
+import math
 import sys
 import time
 from dataclasses import dataclass
@@ -22,6 +25,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from bench.check import Burst, Log, check
+from bench.membership import Schedule, store_devices
 from bench.records import Pool, digest
 from bench.trace import WINDOW_SPAN
 from bench.traffic import Stream, prefill_keys
@@ -117,6 +121,15 @@ class Overflow(Exception):
     """The program refused a burst (its store window would wrap)."""
 
 
+class Refused(Overflow):
+    """The program refused a membership change; the ``stranded`` ops,
+    due by then and not yet dispatched, never get a reply."""
+
+    def __init__(self, msg: str, stranded: int):
+        super().__init__(msg)
+        self.stranded = stranded
+
+
 class CellRun:
     """One run of one cell.  ``structure`` replaces the program (the
     control, and the tests' faults); ``trace`` adds the host spans and the
@@ -133,6 +146,9 @@ class CellRun:
         self.tiers = cfg["tiers"]
         self.inputs, self.outputs = cfg["inputs"], cfg["outputs"]
         self.mix = cell.mix
+        self.plan = Schedule(self.mix.get("membership", []))
+        if self.plan.changes and self.mix["loop"] != "open":
+            raise ValueError("a membership schedule needs an open loop")
         self.q = (structure if structure is not None
                   else build_structure(cfg, self.n, devices))
         self.pool = Pool(self.K * self.n * self.L, self.W, seed,
@@ -141,8 +157,13 @@ class CellRun:
         self.spans: Dict[str, List[float]] = {}
         self.failed = 0
         self.window_log_start = 0
+        self.window_log_end = None     # set where bursts follow the window
         self.latencies = None
         self.traced_bursts = None
+        self.traced_from = 0
+        self._trace_dir = None
+        self.members, self.pool_ids = [], frozenset()   # as scheduled
+        self.marks = []            # (first burst, membership as scheduled)
 
     # ------------------------------------------------------------ spans --
     @contextlib.contextmanager
@@ -230,11 +251,41 @@ class CellRun:
                           k.reshape(self.K, N) if self.tiers > 1 else None)
         warm = Stream(dict(self.mix, loop="closed"), self.seed + 1,
                       self.tiers)
+        self._warm(warm)
+        if self.plan.changes:
+            # one pass of the schedule: the wave programs of every
+            # membership and the migration programs between them
+            self.members = list(self.q.device_ids)
+            self.pool_ids = frozenset(self.members)
+            self.marks.append((0, store_devices(self.q) == self.pool_ids))
+            for change in Schedule(self.mix["membership"]).changes:
+                self._apply(change)
+                self._warm(warm)
+        self.window_log_start = len(self.log.bursts)
+
+    def _warm(self, warm: Stream) -> None:
+        """Mixed bursts at each width the window uses, on the mesh the
+        structure has now."""
+        n = self.q.n_shards
         widths = self.widths()
         for w in widths * (WARM_BURSTS if len(widths) == 1 else 1):
-            n = self.K * self.n * w
-            self.dispatch(*self._full(warm.take(n), self.n * w))
-        self.window_log_start = len(self.log.bursts)
+            self.dispatch(*self._full(warm.take(self.K * n * w), n * w))
+
+    def _apply(self, change) -> None:
+        """One membership change through the program's own path; keeps
+        its record, and whether the store then sits on the devices the
+        schedule says."""
+        if change.op == "leave":
+            gone = self.members[change.shard]
+            change.stats = self.q.shrink_devices(
+                [self.q.device_ids[change.shard]])
+            self.members.remove(gone)
+        else:
+            change.stats = self.q.grow(1)
+            self.members.append(min(self.pool_ids - set(self.members)))
+        change.devices = store_devices(self.q)
+        self.marks.append((len(self.log.bursts),
+                           change.devices == frozenset(self.members)))
 
     # ------------------------------------------------------------ window --
     def window(self, seconds: float, clock=time.perf_counter,
@@ -242,9 +293,12 @@ class CellRun:
         """Drive the window; returns ``attempted``, ``answered`` and the
         window's ``seconds`` (the whole time, to the last reply).  With
         ``trace_dir`` the profiler records the window's first bursts
-        there (``TRACE_BURSTS``)."""
+        there (``TRACE_BURSTS``); with a membership schedule, from just
+        before the first change to the last one's recovery."""
         stream = Stream(self.mix, self.seed, self.tiers)
-        self._trace_start(trace_dir)
+        self.traced_from = len(self.log.bursts)
+        self._trace_dir = trace_dir
+        self._trace_start(None if self.plan.changes else trace_dir)
         try:
             if self.mix["loop"] == "open":
                 return self._open(stream, seconds, clock, sleep)
@@ -266,7 +320,10 @@ class CellRun:
 
     def _tick(self) -> None:
         """After each dispatch: end the trace once it is long enough."""
-        if self._tracing is not None and len(self.log.bursts) - \
+        if self.plan.changes:
+            if self._tracing is not None and self.plan.done:
+                self._trace_stop()
+        elif self._tracing is not None and len(self.log.bursts) - \
                 self.window_log_start >= max(2, TRACE_BURSTS // self.n):
             self._trace_stop()
 
@@ -277,7 +334,7 @@ class CellRun:
         self._tracing.__exit__(None, None, None)
         self._tracing = None
         jax.profiler.stop_trace()
-        self.traced_bursts = len(self.log.bursts) - self.window_log_start
+        self.traced_bursts = len(self.log.bursts) - self.traced_from
 
     def _closed(self, stream, seconds, clock) -> dict:
         N = self.n * self.L
@@ -300,33 +357,46 @@ class CellRun:
         """Ops arrive when due; those due while a dispatch runs make the
         next batch, staged at the narrowest ladder width that holds
         them.  Each op's latency runs from its due time to the moment its
-        batch's replies are on the host."""
+        batch's replies are on the host.  A membership change due is made
+        between dispatches; bursts are as wide as the shards then are."""
         widths = self.widths()
-        cap = self.K * self.n * widths[-1]
+        plan = self.plan
         lat, attempted = [], 0
         t0 = clock()
+
+        def on_reply():
+            now = clock() - t0
+            lat.append(now - d)
+            plan.replied(attempted, now)
         try:
             while True:
                 t = clock() - t0
-                due = stream.peek_due(cap)
-                m = int(np.searchsorted(due, min(t, seconds), "right"))
+                if plan.next_at() <= t:
+                    self._change(stream, t, attempted, clock, t0)
+                    continue
+                end = plan.end(seconds)
+                n = self.q.n_shards
+                due = stream.peek_due(self.K * n * widths[-1])
+                m = int(np.searchsorted(due, min(t, end), "right"))
                 if m == 0:
-                    if due[0] >= seconds:
+                    if due[0] >= end:
                         break
-                    sleep(due[0] - t)
+                    sleep(min(due[0], plan.next_at()) - t)
                     continue
                 with self.span("generate"):
                     is_enq, key, d = stream.take(m)
                     attempted += m
-                    w = next(w for w in widths if self.K * self.n * w >= m)
-                    N = self.n * w
+                    w = next(w for w in widths if self.K * n * w >= m)
+                    N = n * w
                     flat = np.zeros((3, self.K * N), np.int32)
                     flat[0, :m], flat[1, :m], flat[2, :m] = is_enq, 1, key
                     shaped = flat.reshape(3, self.K, N)
                 self.dispatch(shaped[0].astype(bool), shaped[1].astype(bool),
                               shaped[2] if self.tiers > 1 else None,
-                              on_reply=lambda: lat.append(clock() - t0 - d))
+                              on_reply=on_reply)
                 self._tick()
+        except Refused as err:
+            attempted += err.stranded
         except Overflow:
             pass
         self.latencies = np.concatenate(lat) if lat else np.zeros(0)
@@ -334,13 +404,78 @@ class CellRun:
         return {"attempted": attempted, "answered": answered,
                 "seconds": clock() - t0}
 
+    def _change(self, stream, t, attempted, clock, t0) -> None:
+        """Issue the schedule's next change at window time ``t``; it
+        recovers once the ops due before it returned are answered.  A
+        change the program refuses ends the window: the ops due by then
+        that were not dispatched fail."""
+        plan = self.plan
+        if plan.issued == 0 and self._trace_dir is not None:
+            self.traced_from = len(self.log.bursts)
+            self._trace_start(self._trace_dir)
+        c = plan.changes[plan.issued]
+        c.issued = t
+        try:
+            self._apply(c)
+        except (ValueError, RuntimeError) as err:
+            stranded = stream.count_due(clock() - t0)
+            self.failed += stranded
+            raise Refused(f"{c.op} refused: {err}", stranded) from err
+        c.returned = clock() - t0
+        c.target = attempted + stream.count_due(c.returned)
+        plan.issued += 1
+
+    def drain(self) -> int:
+        """With a membership schedule, after the window: dequeue-only
+        bursts at full width on the membership the window left, through
+        the same ``run_waves`` program, until one finds the queue empty.
+        The check then compares every record the store held, each one the
+        changes migrated among them, and not only those the window
+        dequeued.  Untimed; returns the ops it dequeued."""
+        self.window_log_end = len(self.log.bursts)
+        if not self.plan.changes:
+            return 0
+        N = self.q.n_shards * self.L
+        valid = np.ones((self.K, N), bool)
+        key = np.zeros((self.K, N), np.int32) if self.tiers > 1 else None
+        most = self.n * self.cell.config["store_records_per_chip"]
+        dequeued = 0
+        for _ in range(most // (self.K * N) + 2):
+            try:
+                self.dispatch(~valid, valid, key)
+            except Overflow:
+                break
+            got = self.log.bursts[-1].got["dok"]
+            dequeued += int(got.sum())
+            if not got.all():
+                break
+        return dequeued
+
     # ------------------------------------------------------------- check --
     def check(self) -> Dict[str, int]:
-        """Every op of the run (set-up's included) against the reference."""
+        """Every op of the run (set-up's included) against the reference.
+        The reference's ring maps live positions to ids: it is as long as
+        the largest store the run has, which holds the live span of every
+        membership.  It does not shrink with a LEAVE: a ring as long as
+        the capacity in force would wrap where a program that accepts ops
+        past that capacity wraps, and agree with it.  Where the schedule
+        changes membership, every op answered while the store sat on
+        other devices than the schedule says is wrong, and each scheduled
+        change not made counts."""
         window = (self.n * self.cell.config["store_records_per_chip"]
                   // self.tiers)
         replay = self.cell.reference.Replay(self.tiers, window)
-        return check(self.log, replay, self.pool)
+        if not self.plan.changes:
+            return check(self.log, replay, self.pool)
+        ends = [i for i, _ in self.marks[1:]] + [len(self.log.bursts)]
+        void = {b for (i, ok), end in zip(self.marks, ends) if not ok
+                for b in range(i, end)}
+        numbers = check(self.log, replay, self.pool, void)
+        numbers["wrong_membership"] = sum(
+            int(self.log.bursts[b].valid.sum()) for b in void)
+        numbers["changes_not_made"] = sum(
+            math.isnan(c.returned) for c in self.plan.changes)
+        return numbers
 
     def free(self) -> None:
         """Drop the program's state so that the check runs beside an
@@ -351,10 +486,11 @@ class CellRun:
 
     def _measured(self) -> slice:
         """The window's bursts, or in the traced run the traced ones."""
-        start = self.window_log_start
         if self.traced_bursts is None:
-            return slice(start, len(self.log.bursts))
-        return slice(start, start + self.traced_bursts)
+            end = self.window_log_end
+            return slice(self.window_log_start,
+                         len(self.log.bursts) if end is None else end)
+        return slice(self.traced_from, self.traced_from + self.traced_bursts)
 
     def window_counts(self) -> dict:
         """Ops, enqueues and answered dequeues of the measured bursts."""
@@ -374,6 +510,13 @@ class CellRun:
 
 
 LIMITS = {"wrong_ops": 0}
+MEMBERSHIP_LIMITS = {"changes_not_made": 0}
+
+
+def limits_for(run: CellRun) -> dict:
+    """The compared numbers and their limits: with a membership schedule,
+    every scheduled change has to be made as well."""
+    return dict(LIMITS, **(MEMBERSHIP_LIMITS if run.plan.changes else {}))
 
 
 @dataclass
@@ -453,6 +596,13 @@ def run_cell(layout: Layout, cell: Cell, seed: int, seconds: float,
     with tempfile.TemporaryDirectory(prefix="bench-trace-") as tdir:
         win = run.window(seconds, trace_dir=tdir if trace else None)
         in_window = counter.total - before
+        if run.plan.changes:
+            t = time.perf_counter()
+            drained = run.drain()
+            _say(f"drain after the window: {drained} records dequeued in "
+                 f"{len(run.log.bursts) - run.window_log_end} bursts, "
+                 f"{time.perf_counter() - t:.3f} s, "
+                 f"{counter.total - before - in_window} compiles")
         mem = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
                   for d in devices)
         run.free()
@@ -467,19 +617,25 @@ def run_cell(layout: Layout, cell: Cell, seed: int, seconds: float,
     _say(f"window: {win['attempted']} ops attempted, {win['answered']} "
          f"answered in {win['seconds']:.6f} s; {run.failed} failed; "
          f"{in_window} compiles in the window")
+    for c in run.plan.changes:
+        _say(f"{c.op}: issued at {c.issued:.6f} s, returned "
+             f"{c.returned:.6f}, recovered {c.recovered:.6f}; moved "
+             f"{c.stats.get('moved')} records; store on devices "
+             f"{sorted(c.devices)}")
     t = time.perf_counter()
     numbers = run.check()
     _say(f"check of {numbers['ops_checked']} ops (every op of the run) "
          f"took {time.perf_counter() - t:.3f} s; wrong replies "
          f"{numbers['wrong_replies']}, wrong records "
          f"{numbers['wrong_records']}")
+    limits = limits_for(run)
     res = Result(run, win, setup_seconds, peaks, tr)
     metrics = read_metrics(layout, cell.per_layer if trace
                            else cell.end_to_end, res)
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind,
               "count": jax.device_count(), "memory_peak_bytes": mem}
-    out = {"correct": all(numbers[k] <= lim for k, lim in LIMITS.items())
+    out = {"correct": all(numbers[k] <= lim for k, lim in limits.items())
            and win["answered"] > 0,
            "attempted": win["attempted"], "failed": run.failed,
            "metrics": metrics, "device": device}
@@ -489,7 +645,7 @@ def run_cell(layout: Layout, cell: Cell, seed: int, seconds: float,
         out["breakdown"] = {"device_ops": tr.top_ops(10),
                             "idle_gaps": tr.idle_by_host_span(10)}
     out["check"] = {k: {"value": numbers[k], "limit": lim}
-                    for k, lim in LIMITS.items()}
+                    for k, lim in limits.items()}
     return out
 
 

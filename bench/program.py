@@ -15,16 +15,38 @@ import numpy as np
 NONE = "(none)"
 
 
+CHANGE_SPANS = ("migration:shrink", "migration:grow")
+
+
+def window_spans(r, names) -> Optional[list]:
+    """The program's host spans called one of ``names`` that lie in the
+    traced window, as ``(start_ns, end_ns, name)``; None without a
+    trace."""
+    if r.trace is None:
+        return None
+    lo, hi = r.trace.window
+    return [h for h in r.trace.host if h[2] in names and h[0] >= lo
+            and h[1] <= hi]
+
+
+def change_device_ns(r) -> Optional[List[float]]:
+    """Per membership change in the traced window, the device busy time
+    inside its ``migration:shrink`` or ``migration:grow`` span, averaged
+    over the devices (no wave runs while a change does); None without a
+    trace or without such spans."""
+    spans = window_spans(r, CHANGE_SPANS)
+    if not spans:
+        return None
+    return [r.trace.busy_between_ns(s, e) for s, e, _ in spans]
+
+
 def span_ms(r, name: str) -> Optional[float]:
     """Mean duration, in ms, of the host spans called ``name`` that lie in
     the traced window (the program's spans carry one per burst); None
     without a trace or without such spans."""
-    if r.trace is None:
-        return None
-    lo, hi = r.trace.window
-    d = [e - s for s, e, n in r.trace.host if n == name and s >= lo
-         and e <= hi]
-    return 1e-6 * float(np.mean(d)) if d else None
+    spans = window_spans(r, (name,))
+    return 1e-6 * float(np.mean([e - s for s, e, _ in spans])) \
+        if spans else None
 
 
 def self_time(start: np.ndarray, end: np.ndarray) -> np.ndarray:

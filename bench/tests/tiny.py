@@ -50,15 +50,23 @@ def cell_named(layout, name):
                 layout.reference("priority"), e2e, [])
 
 
+MEMBERSHIP_TIME = 0.03   # a membership schedule's times, scaled
+
+
 def tiny(cell):
     """The cell at tiny sizes: the backlog keeps its share of the store,
-    records keep at most 8 words, an open loop offers 2000 ops/s."""
+    records keep at most 8 words, an open loop offers 2000 ops/s, and a
+    membership schedule's times shrink with the window."""
     cfg = dict(cell.config, **SIZES,
                record_words=min(cell.config["record_words"], 8))
     mix = dict(cell.mix, backlog_records=cell.mix["backlog_records"] * STORE
                // cell.config["store_records_per_chip"])
     if mix["loop"] == "open":
         mix["rate_ops_per_s"] = 2000
+    if "membership" in mix:
+        mix["membership"] = [
+            {k: v * MEMBERSHIP_TIME if k.endswith("_s") else v
+             for k, v in c.items()} for c in mix["membership"]]
     return dataclasses.replace(cell, config=cfg, mix=mix)
 
 

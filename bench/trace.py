@@ -21,7 +21,7 @@ import numpy as np
 
 WINDOW_SPAN = "bench:window"
 OPS_LINE = "XLA Ops"
-SPAN_PREFIXES = ("bench:", "queue:", "pqueue:")
+SPAN_PREFIXES = ("bench:", "queue:", "pqueue:", "migration:")
 
 
 def _arrays(intervals) -> Tuple[np.ndarray, np.ndarray]:
@@ -116,6 +116,11 @@ class Trace:
     def busy_ns(self) -> float:
         """Busy time, averaged over the devices."""
         return float(np.mean([length(self.ops(d)) for d in self.devices]))
+
+    def busy_between_ns(self, lo: float, hi: float) -> float:
+        """Busy time inside ``[lo, hi]``, averaged over the devices."""
+        return float(np.mean([length(clip(self.ops(d), lo, hi))
+                              for d in self.devices]))
 
     def exposed_ns(self, kind: str) -> float:
         """Time ops of ``kind`` run with no other op running, averaged

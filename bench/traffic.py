@@ -13,7 +13,10 @@ A mix (``traffic/<name>.json``) gives:
 * ``backlog_records``: the backlog set-up enqueues before the window, in
   enqueue-only bursts, into tiers by ``prefill_key_shares`` (default
   ``key_shares``);
-* open loop only: ``arrivals`` (``"poisson"``) and ``rate_ops_per_s``.
+* open loop only: ``arrivals`` (``"poisson"``) and ``rate_ops_per_s``;
+* open loop only, optional: ``membership``, the LEAVEs and JOINs the
+  window makes while ops keep arriving on their schedule (see
+  ``bench/membership.py``); without it the structure keeps its shards.
 
 Every op is drawn in one fixed order from one generator seeded by
 ``--seed``, in chunks of ``CHUNK``: the same seed gives the same stream
@@ -65,6 +68,16 @@ class Stream:
         """Due times of the next n ops, without taking them."""
         self.available(n)
         return self._buf[2][:n]
+
+    def count_due(self, t: float) -> int:
+        """How many of the ops not yet taken are due by ``t`` (open
+        loop)."""
+        if self.rate is None:
+            raise ValueError("a closed loop's ops have no due times")
+        n = CHUNK
+        while self.peek_due(n)[-1] <= t:
+            n *= 2
+        return int(np.searchsorted(self.peek_due(n), t, "right"))
 
     def take(self, n: int):
         self.available(n)
