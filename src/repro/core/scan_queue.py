@@ -262,58 +262,64 @@ def priority_queue_scan(is_enq: jax.Array, prio: jax.Array, valid: jax.Array,
     P_ = n_prios
     enq = is_enq & valid
     deq = (~is_enq) & valid
-    tier = jnp.full(is_enq.shape, -1, jnp.int32)
-    pos = jnp.full(is_enq.shape, BOTTOM, jnp.int32)
-    if tier_scan is not None:
-        pos_e, new_lasts = tier_scan(enq, prio, firsts, lasts)
-        tier = jnp.where(enq & (pos_e >= 0), prio.astype(jnp.int32), tier)
-        pos = jnp.where(enq, pos_e, pos)
-    else:
-        new_lasts = []
-        for p in range(P_):
-            mask = enq & (prio == p)
-            pos_p, _, st_p = queue_scan(
-                mask, QueueState(firsts[p], lasts[p]), valid=mask)
-            tier = jnp.where(mask, p, tier)
-            pos = jnp.where(mask, pos_p, pos)
-            new_lasts.append(st_p.last)
-        new_lasts = jnp.stack(new_lasts)
-    avail = new_lasts - firsts + 1                      # sizes after enqueues
+    # the two wave phases of the dispatch (``WAVE_PHASES``)
+    with jax.named_scope("tier_scan"):
+        tier = jnp.full(is_enq.shape, -1, jnp.int32)
+        pos = jnp.full(is_enq.shape, BOTTOM, jnp.int32)
+        if tier_scan is not None:
+            pos_e, new_lasts = tier_scan(enq, prio, firsts, lasts)
+            tier = jnp.where(enq & (pos_e >= 0), prio.astype(jnp.int32),
+                             tier)
+            pos = jnp.where(enq, pos_e, pos)
+        else:
+            new_lasts = []
+            for p in range(P_):
+                mask = enq & (prio == p)
+                pos_p, _, st_p = queue_scan(
+                    mask, QueueState(firsts[p], lasts[p]), valid=mask)
+                tier = jnp.where(mask, p, tier)
+                pos = jnp.where(mask, pos_p, pos)
+                new_lasts.append(st_p.last)
+            new_lasts = jnp.stack(new_lasts)
+        avail = new_lasts - firsts + 1              # sizes after enqueues
+    with jax.named_scope("deletemin"):
+        if relaxation == 0:
+            # strict: pure per-tier prefix arithmetic, no sequential loop
+            t_c, pos_d, d_matched, taken = strict_batch_deletemin(
+                deq, avail, firsts, P_)
+            tier = jnp.where(d_matched, t_c, tier)
+            pos = jnp.where(d_matched, pos_d, pos)
+            matched = enq | d_matched
+            n_relaxed = jnp.int32(0)
+        else:
+            if shard_of is None or n_shards is None:
+                raise ValueError(
+                    "relaxation > 0 needs shard_of and n_shards")
+            ar = jnp.arange(P_, dtype=jnp.int32)
 
-    if relaxation == 0:
-        # strict: pure per-tier prefix arithmetic, no sequential loop
-        t_c, pos_d, d_matched, taken = strict_batch_deletemin(
-            deq, avail, firsts, P_)
-        tier = jnp.where(d_matched, t_c, tier)
-        pos = jnp.where(d_matched, pos_d, pos)
-        matched = enq | d_matched
-        n_relaxed = jnp.int32(0)
-    else:
-        if shard_of is None or n_shards is None:
-            raise ValueError("relaxation > 0 needs shard_of and n_shards")
-        ar = jnp.arange(P_, dtype=jnp.int32)
+            def step(taken, x):
+                d_i, s_i = x
+                sizes = avail - taken
+                ne = sizes > 0
+                pstar = jnp.argmax(ne).astype(jnp.int32)  # best non-empty
+                heads = firsts + taken
+                loc = (ne & (ar >= pstar) & (ar <= pstar + relaxation)
+                       & (jnp.mod(heads, n_shards) == s_i))
+                q = jnp.where(loc.any(), jnp.argmax(loc),
+                              pstar).astype(jnp.int32)
+                m = d_i & ne.any()
+                out = (jnp.where(m, q, -1), jnp.where(m, heads[q], BOTTOM),
+                       m, m & (q != pstar))
+                return (taken + jnp.where(m, (ar == q).astype(jnp.int32), 0),
+                        out)
 
-        def step(taken, x):
-            d_i, s_i = x
-            sizes = avail - taken
-            ne = sizes > 0
-            pstar = jnp.argmax(ne).astype(jnp.int32)    # best non-empty tier
-            heads = firsts + taken
-            loc = (ne & (ar >= pstar) & (ar <= pstar + relaxation)
-                   & (jnp.mod(heads, n_shards) == s_i))
-            q = jnp.where(loc.any(), jnp.argmax(loc), pstar).astype(jnp.int32)
-            m = d_i & ne.any()
-            out = (jnp.where(m, q, -1), jnp.where(m, heads[q], BOTTOM),
-                   m, m & (q != pstar))
-            return taken + jnp.where(m, (ar == q).astype(jnp.int32), 0), out
-
-        taken, (t_d, pos_d, m_d, rel) = lax.scan(
-            step, jnp.zeros((P_,), jnp.int32),
-            (deq, shard_of.astype(jnp.int32)))
-        tier = jnp.where(m_d, t_d, tier)
-        pos = jnp.where(m_d, pos_d, pos)
-        matched = enq | m_d
-        n_relaxed = rel.astype(jnp.int32).sum()
+            taken, (t_d, pos_d, m_d, rel) = lax.scan(
+                step, jnp.zeros((P_,), jnp.int32),
+                (deq, shard_of.astype(jnp.int32)))
+            tier = jnp.where(m_d, t_d, tier)
+            pos = jnp.where(m_d, pos_d, pos)
+            matched = enq | m_d
+            n_relaxed = rel.astype(jnp.int32).sum()
 
     return tier, pos, matched, firsts + taken, new_lasts, n_relaxed
 

@@ -318,9 +318,17 @@ class _ElasticBase:
         membership it has had: instruction name -> wave phase, from the
         program's compiled text (see ``WaveEngine.phase_tables``).  Read
         after the fact; nothing compiles."""
-        return [t for inner in self._inner_cache.values()
-                if getattr(inner, "engine", None) is not None
-                for t in inner.engine.phase_tables()]
+        return [t for e in self._engines() for t in e.phase_tables()]
+
+    def wave_programs(self) -> list:
+        """``(key, compiled text)`` of each wave program this structure has
+        dispatched, on every membership it has had (see
+        ``WaveEngine.compiled_programs``); nothing compiles."""
+        return [p for e in self._engines() for p in e.compiled_programs()]
+
+    def _engines(self) -> list:
+        return [inner.engine for inner in self._inner_cache.values()
+                if getattr(inner, "engine", None) is not None]
 
     def _place(self, x, lead: int = 0):
         """Stage one op array onto the active mesh via the runtime

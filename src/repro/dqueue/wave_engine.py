@@ -86,8 +86,11 @@ TAG_GET = 2
 # The ``jax.named_scope`` of each phase of a wave: every operation of the
 # wave bodies sits under one of them, so a compiled program's instructions
 # (and a device trace's ops) can be put to the phase that issued them.
+# ``tier_scan`` and ``deletemin`` nest inside the priority discipline's
+# ``dispatch`` (``core.scan_queue.priority_queue_scan``); the innermost
+# scope names an instruction's phase.
 WAVE_PHASES = ("dispatch", "pack", "exchange", "commit", "reply", "merge",
-               "telemetry")
+               "telemetry", "tier_scan", "deletemin")
 
 
 def program_name(discipline: str, entry: str) -> str:
@@ -305,7 +308,7 @@ class WaveEngine:
         self._mstate = self.init_metrics_state() if self.metrics else None
         self._seq0 = 0  # waves drained-and-reset before the current ring
         # (entry, op shapes) -> abstract arguments of each program
-        # dispatched, for :meth:`phase_tables`
+        # dispatched, for :meth:`compiled_programs`
         self._dispatched: dict = {}
         self._step = self._build_step()
         self._run_waves = self._build_run_waves()
@@ -560,20 +563,25 @@ class WaveEngine:
         st, self._mstate = out[0]
         return (st,) + tuple(out[1:])
 
-    def phase_tables(self) -> list:
-        """For each program this engine has dispatched (one per entry
-        point, burst length and width), the phase of each instruction of
-        its compiled text: ``{instruction name: phase}`` over
-        :data:`WAVE_PHASES`.  Instruction names are those a device trace
-        gives its ops.  The executables come from jax's cache of the
-        programs already run; nothing compiles."""
-        from ..analysis.hlo import scope_table
-        tables = []
+    def compiled_programs(self) -> list:
+        """``(key, compiled text)`` of each program this engine has
+        dispatched: one per entry point, burst length and width, ``key``
+        being ``(entry, shape of each op array)``.  The executables come
+        from jax's cache of the programs already run; nothing compiles."""
+        out = []
         for key, args in self._dispatched.items():
             prog = self._run_waves if key[0] == "waves" else self._step
-            text = prog.lower(*args).compile().as_text()
-            tables.append(scope_table(text, WAVE_PHASES))
-        return tables
+            out.append((key, prog.lower(*args).compile().as_text()))
+        return out
+
+    def phase_tables(self) -> list:
+        """For each program of :meth:`compiled_programs`, the phase of each
+        instruction: ``{instruction name: phase}`` over
+        :data:`WAVE_PHASES`.  Instruction names are those a device trace
+        gives its ops."""
+        from ..analysis.hlo import scope_table
+        return [scope_table(t, WAVE_PHASES)
+                for _, t in self.compiled_programs()]
 
     # ----------------------------------------------------- metrics drain ---
     def init_metrics_state(self) -> MetricsState:

@@ -254,6 +254,36 @@ def test_priority_scan_pallas_matches_core():
             assert (np.asarray(a) == np.asarray(b)).all(), P_
 
 
+@pytest.mark.parametrize("n", [2048, 4096, 8192])
+def test_fused_sweep_matches_jnp_scan_at_each_ladder_width(n):
+    """At the bucket ladder's widths of an 8192-op wave, the fused
+    segscan sweep (interpret mode) inside ``priority_queue_scan`` answers
+    every op as the jnp scans do, four tiers at skewed shares."""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.scan_queue import priority_queue_scan
+    from repro.kernels.segscan import make_tier_scan
+
+    rng = np.random.default_rng(n)
+    is_enq = jnp.array(rng.random(n) < 0.5)
+    valid = jnp.array(rng.random(n) < 0.95)
+    prio = jnp.array(rng.choice(4, n, p=[0.05, 0.55, 0.15, 0.25]),
+                     jnp.int32)
+    firsts = jnp.array(rng.integers(0, 1 << 20, 4), jnp.int32)
+    lasts = firsts + jnp.array([-1, 3, 0, 900], jnp.int32)
+
+    def scan(tier_scan):
+        return jax.jit(lambda *a: priority_queue_scan(
+            *a, n_prios=4, tier_scan=tier_scan))(is_enq, prio, valid,
+                                                 firsts, lasts)
+    ref = scan(None)
+    got = scan(make_tier_scan(4, interpret=True))
+    for name, a, b in zip(("tier", "pos", "matched", "firsts", "lasts",
+                           "n_relaxed"), got, ref):
+        assert (np.asarray(a) == np.asarray(b)).all(), (n, name)
+    assert int(np.asarray(ref[2]).sum()) > n // 2
+
+
 # The post-enqueue-peak overflow regression moved to
 # tests/test_wave_engine.py::test_overflow_surfaces_once_for_all_disciplines
 # when the check itself was deduplicated into

@@ -148,3 +148,40 @@ def test_fifo_burst_scan_is_its_only_loop(fifo_burst, n_dev):
     in_reply = [w for w in loops if phases.get(w) == "reply"]
     assert in_reply == [], in_reply
     assert len(loops) == 1, loops          # the burst's lax.scan
+
+
+@pytest.mark.parametrize("width", [2048, 4096, 8192])
+def test_scheduler_burst_keeps_its_dispatch_free_of_loops(topo,
+                                                          compiled_kernels,
+                                                          width):
+    """The 4-tier burst of 32-B records over a 2^23-record store (the
+    request scheduler's deployment), at each ladder width of an 8192-op
+    wave: the segscan sweep's kernels sit in phase ``tier_scan``, and no
+    loop sits in the dispatch, its tier scan or its batch-DeleteMin."""
+    mesh = _mesh(topo, 1)
+    q = DevicePriorityQueue(mesh, "data", n_prios=4, cap=1 << 21,
+                            payload_width=8, ops_per_shard=L)
+    state = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                          sharding=NamedSharding(mesh, s)),
+        jax.eval_shape(q.init_state), q.engine.disc.state_specs)
+    op = NamedSharding(mesh, P(None, "data"))
+    ops = [jax.ShapeDtypeStruct((K, width), dt, sharding=op)
+           for dt in (jnp.bool_, jnp.bool_, jnp.int32)]
+    ops.append(jax.ShapeDtypeStruct((K, width, 8), jnp.int32, sharding=op))
+    text = q._run_waves.lower(state, *ops).compile().as_text()
+    phases = scope_table(text, WAVE_PHASES)
+    kernels = [line.split("=")[0].strip().lstrip("%")
+               for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 2
+    assert {phases.get(k) for k in kernels} == {"tier_scan"}
+    ops = parse_hlo(text).ops
+    loops = [op.var.lstrip("%") for op in ops if op.opcode == "while"]
+    in_dispatch = [w for w in loops
+                   if phases.get(w) in ("dispatch", "tier_scan", "deletemin")]
+    assert in_dispatch == [], in_dispatch
+    # each op's tier row is selected, not gathered one scalar at a time
+    gathers = [op.var.lstrip("%") for op in ops if op.opcode == "gather"
+               and phases.get(op.var.lstrip("%")) == "tier_scan"]
+    assert gathers == [], gathers

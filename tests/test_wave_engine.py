@@ -608,6 +608,63 @@ def test_programs_are_named_and_every_scatter_gather_is_phased(kind):
                 WAVE_PHASES), (kind, entry, opts, op, meta)
 
 
+# The phases the FIFO and LIFO waves had before the priority dispatch
+# split its own into ``tier_scan`` and ``deletemin``.
+BASE_PHASES = ("dispatch", "pack", "exchange", "commit", "reply", "merge",
+               "telemetry")
+
+
+@pytest.mark.parametrize("kind", ["fifo", "lifo"])
+def test_fifo_and_lifo_phase_tables_are_unchanged(kind):
+    from repro.analysis.hlo import scope_table
+    from repro.dqueue.wave_engine import WAVE_PHASES
+
+    for entry, opts, text in _lowered_wave_programs(kind):
+        table = scope_table(text, WAVE_PHASES)
+        assert table == scope_table(text, BASE_PHASES), (entry, opts)
+        assert not {"tier_scan", "deletemin"} & set(table.values())
+
+
+def test_priority_programs_phase_tier_scan_and_deletemin():
+    """Inside the priority dispatch, the per-tier enqueue scan and the
+    in-wave batch-DeleteMin are phases of their own; the rest of the
+    dispatch stays ``dispatch``."""
+    from repro.analysis.hlo import scope_table
+    from repro.dqueue.wave_engine import WAVE_PHASES
+
+    for entry, opts, text in _lowered_wave_programs("prio"):
+        phases = set(scope_table(text, WAVE_PHASES).values())
+        assert {"dispatch", "tier_scan", "deletemin", "commit",
+                "reply"} <= phases, (entry, opts, phases)
+
+
+def test_elastic_priority_queue_gives_its_programs_texts_and_tables(
+        monkeypatch):
+    """``wave_programs`` and ``wave_phases`` read one compiled program per
+    dispatched width, with the fused sweep (interpret mode) as on a TPU,
+    and compile nothing."""
+    from repro.analysis import CompilationTracker
+    from repro.dqueue import ElasticDevicePriorityQueue, priority_queue
+
+    monkeypatch.setattr(priority_queue, "use_fused_dispatch", lambda: True)
+    q = ElasticDevicePriorityQueue(1, n_prios=4, cap=64, payload_width=8,
+                                   ops_per_shard=8)
+    assert q.inner.engine.disc.fused_dispatch
+    rng = np.random.default_rng(0)
+    for w in (4, 8):
+        e = rng.random((2, w)) < 0.6
+        q.run_waves(e, np.ones((2, w), bool),
+                    rng.integers(0, 4, (2, w)).astype(np.int32),
+                    np.zeros((2, w, 8), np.int32))
+    with CompilationTracker() as t:
+        programs, tables = q.wave_programs(), q.wave_phases()
+    assert t.count == 0
+    assert len(programs) == len(tables) == 2
+    assert sorted(key[1] for key, _ in programs) == [(2, 4), (2, 8)]
+    for table in tables:
+        assert {"tier_scan", "deletemin"} <= set(table.values())
+
+
 # --------------------------------------------------------------------------
 # Reply extraction: each op's reply row is selected from the [n, L, 1+W]
 # reply buffer with a mask over the n rows, never gathered per op (XLA:TPU
